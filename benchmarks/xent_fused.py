@@ -47,7 +47,7 @@ CHUNK = 2048  # cfg.loss_chunk default
 def _tiles(N, D, V, be):
     from repro.kernels.xent.xent import _pick_blocks
     fwd = _pick_blocks(N, D, V, el_bytes=be)
-    dh = _pick_blocks(N, D, V, el_bytes=be, row_acc=True)
+    dh = _pick_blocks(N, D, V, el_bytes=be, out_bytes=be, kernel="dh")
     return fwd, dh
 
 

@@ -78,6 +78,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..vmem import COMPILER_PARAMS, VMEM_BUDGET_BYTES, tile_bytes
 from .mask import MaskSpec, mask_spec
 
 _NEG = -1e30  # finite -inf stand-in: keeps the running max NaN-free when a
@@ -89,11 +90,14 @@ def _pick_tiles(S: int, T: int, hd: int, hdv: int, block=None, *,
                 el_bytes: int = 4):
     """(bq, bk) tile for one kernel, clamped to the (padded) problem.
 
-    Both tiles grow until the per-step VMEM working set — q/k/v blocks,
-    the f32 (bq, bk) score tile, and the f32 output/dQ accumulator —
-    reaches ~8 MiB, shrinking bk first (k/v stream per q tile; a bigger bq
-    is the HBM-reuse lever). Caps at 512, floors at the (8, 128) hardware
-    tiling; the clamp keeps tiny problems to a single tile.
+    Both tiles start at 512 and shrink until the scoped-VMEM working set
+    of the largest of the three kernels fits the budget (see
+    :mod:`repro.kernels.vmem`): every q/k/v/dout/output block twice
+    (double-buffered, lanes padded — hd 64 costs as 128), the (bq, 1) row
+    statistics, the f32 accumulators, and six f32 (bq, bk) score-sized
+    temporaries (scores, p, dp, ds and the row/column iotas). bk shrinks
+    first (k/v stream per q tile; a bigger bq is the HBM-reuse lever).
+    Floors at 128; the clamp keeps tiny problems to a single tile.
     """
     if block is not None:
         bq, bk = block
@@ -101,12 +105,17 @@ def _pick_tiles(S: int, T: int, hd: int, hdv: int, block=None, *,
         bq = bk = 512
 
         def cost(bq, bk):
-            return ((bq + bk) * hd + bk * hdv) * el_bytes \
-                + (bq * bk + bq * hdv + bq * hd) * 4
+            q, qv = tile_bytes(bq, hd, el_bytes), tile_bytes(bq, hdv, el_bytes)
+            k, v = tile_bytes(bk, hd, el_bytes), tile_bytes(bk, hdv, el_bytes)
+            vec = tile_bytes(bq, 1, 4)
+            blocks = q + k + v + qv + max(q, qv) + k + v + 3 * vec
+            scratch = (tile_bytes(bq, max(hd, hdv), 4) + tile_bytes(bk, hd, 4)
+                       + tile_bytes(bk, hdv, 4) + 2 * vec)
+            return 2 * blocks + scratch + 6 * tile_bytes(bq, bk, 4) + q + qv
 
-        while cost(bq, bk) > (8 << 20) and bk > 128:
+        while cost(bq, bk) > VMEM_BUDGET_BYTES and bk > 128:
             bk //= 2
-        while cost(bq, bk) > (8 << 20) and bq > 128:
+        while cost(bq, bk) > VMEM_BUDGET_BYTES and bq > 128:
             bq //= 2
     bq = min(bq, -(-S // 8) * 8)
     bk = min(bk, -(-T // 128) * 128)
@@ -310,6 +319,8 @@ def mha_fwd(q, k, v, kv_len=None, *, scale: float, causal: bool = True,
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, hdv), jnp.float32)],
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
+        name="attn_fwd",
     )(qt, kt, vt, kl, qs, ks)
     return jnp.swapaxes(out, 1, 2), lse[..., 0]
 
@@ -389,6 +400,8 @@ def mha_bwd_dq(q, k, v, dout, lse, delta, kv_len=None, *, scale: float,
         out_shape=jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
+        name="attn_bwd_dq",
     )(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
       jnp.swapaxes(dout, 1, 2), lse[..., None], delta[..., None], kl, qs, ks)
     return jnp.swapaxes(dq, 1, 2)
@@ -487,6 +500,8 @@ def mha_bwd_dkv(q, k, v, dout, lse, delta, kv_len=None, *, scale: float,
         scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
                         pltpu.VMEM((bk, hdv), jnp.float32)],
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
+        name="attn_bwd_dkv",
     )(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
       jnp.swapaxes(dout, 1, 2), lse[..., None], delta[..., None], kl, qs, ks)
     return jnp.swapaxes(dk, 1, 2), jnp.swapaxes(dv, 1, 2)

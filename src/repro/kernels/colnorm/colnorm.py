@@ -166,6 +166,7 @@ def norm_sumsq(g: jnp.ndarray, axis: str = "col", block=DEFAULT_BLOCK,
         out_shape=out_shape,
         scratch_shapes=[scratch],
         interpret=interpret,
+        name="colnorm_sumsq",
     )(g, gs_arr)
 
 
@@ -213,6 +214,7 @@ def norm_apply(g, ss, axis: str = "col", block=DEFAULT_BLOCK,
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((L, m, n), out_dtype or g.dtype),
         interpret=interpret,
+        name="colnorm_apply",
     )(g, ss, gs_arr)
 
 
@@ -245,6 +247,7 @@ def update_apply(theta, g, ss, lr, axis: str = "col", block=DEFAULT_BLOCK,
         out_shape=jax.ShapeDtypeStruct((L, m, n), theta.dtype),
         input_output_aliases={0: 0},
         interpret=interpret,
+        name="colnorm_update",
     )(theta, g, ss, lr_arr, gs_arr)
 
 

@@ -157,13 +157,15 @@ the next call instead of serving stale compilations. (Inside an outer
 ``jax.jit`` — e.g. a jitted train step — the read still happens at the
 outer trace time; the outer cache is not keyed on it.)
 
-Every kernel route additionally runs under a **failure guard**: a kernel
-path that raises (a backend that cannot lower the Pallas call, a driver
-regression, or a ``REPRO_FAULTS`` ``dispatch_fail`` injection) degrades to
-the jnp reference instead of killing the run — warned once per (op,
-exception type), counted per op in :func:`fallback_counts` so the training
-driver can surface degradations in its step logs. Kernel failures surface
-at trace/lower time (host-side), which is exactly where the guard sits.
+Every kernel route additionally runs under a **failure guard**: a
+``REPRO_FAULTS`` ``dispatch_fail`` injection, or — off the TPU, where the
+kernels are interpreted — a kernel path that raises, degrades to the jnp
+reference instead of killing the run, warned once per (op, exception
+type) and counted per op in :func:`fallback_counts` so the training
+driver can surface degradations in its step logs. On a TPU a real kernel
+failure re-raises: the chip must never silently run the reference.
+Kernel failures surface at trace/lower time (host-side), which is exactly
+where the guard sits.
 
 Entry points (scalar lr/beta/gscale may be traced schedule outputs). All
 accept ``gscale`` — a scalar multiplied into the gradient at read time
@@ -241,7 +243,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .attention import attention as _ak
@@ -397,7 +399,7 @@ def _mapped(body, plan, n_arrays, n_outs=1):
         in_specs = (sp,) * n_arrays + (P(),) * (len(args) - n_arrays)
         return shard_map(body, mesh=plan.mesh, in_specs=in_specs,
                          out_specs=(sp,) * n_outs if n_outs > 1 else sp,
-                         check_rep=False)(*args)
+                         check_vma=False)(*args)
 
     return wrapped
 
@@ -459,28 +461,40 @@ def _dispatch_fault_gate(op: str) -> None:
     faults.dispatch_gate(op)
 
 
-def _guarded(op: str, kernel_thunk, ref_thunk):
-    """Run the kernel route; degrade to the reference on any failure.
+def _degrade(op: str, e: Exception, ref_thunk):
+    _FALLBACK_COUNTS[op] = _FALLBACK_COUNTS.get(op, 0) + 1
+    key = (op, type(e).__name__)
+    if key not in _FALLBACK_LOGGED:
+        _FALLBACK_LOGGED.add(key)
+        warnings.warn(
+            f"dispatch: kernel route for {op!r} failed "
+            f"({type(e).__name__}: {e}); degrading to the jnp "
+            "reference path")
+    return ref_thunk()
 
-    Catches Exception only: a KeyboardInterrupt or a SimulatedKill
-    (BaseException) must never be absorbed into a silent fallback. The
-    degradation is baked into whatever jit trace is being built, so a
-    compiled train step that hit a failing kernel route keeps running the
-    reference until retraced.
+
+def _guarded(op: str, kernel_thunk, ref_thunk):
+    """Run the kernel route; degrade to the reference on a failure.
+
+    An injected ``REPRO_FAULTS`` ``dispatch_fail`` always degrades. A real
+    kernel failure degrades only off the TPU, where the kernels run in
+    the interpreter: on a TPU it re-raises, so a broken kernel stops the
+    run instead of hiding behind the jnp path. Catches Exception only: a
+    KeyboardInterrupt or a SimulatedKill (BaseException) must never be
+    absorbed into a silent fallback. The degradation is baked into
+    whatever jit trace is being built, so a compiled train step that hit
+    a failing kernel route keeps running the reference until retraced.
     """
     try:
         _dispatch_fault_gate(op)
+    except Exception as e:
+        return _degrade(op, e, ref_thunk)
+    try:
         return kernel_thunk()
     except Exception as e:
-        _FALLBACK_COUNTS[op] = _FALLBACK_COUNTS.get(op, 0) + 1
-        key = (op, type(e).__name__)
-        if key not in _FALLBACK_LOGGED:
-            _FALLBACK_LOGGED.add(key)
-            warnings.warn(
-                f"dispatch: kernel route for {op!r} failed "
-                f"({type(e).__name__}: {e}); degrading to the jnp "
-                "reference path")
-        return ref_thunk()
+        if backend() == "tpu":
+            raise
+        return _degrade(op, e, ref_thunk)
 
 
 # --------------------------------------------------------------------------
@@ -823,7 +837,7 @@ def _xent_fused(vocab_size: int, interp: bool, plan, block,
             return body(h, w, labels)
         hspec, wspec, lspec = _specs(h.ndim, labels.ndim)
         return shard_map(body, mesh=mesh, in_specs=(hspec, wspec, lspec),
-                         out_specs=(lspec, lspec), check_rep=False)(
+                         out_specs=(lspec, lspec), check_vma=False)(
                              h, w, labels)
 
     def _bwd_parts(h, w, labels, lse, gl):
@@ -852,7 +866,7 @@ def _xent_fused(vocab_size: int, interp: bool, plan, block,
         hspec, wspec, lspec = _specs(h.ndim, labels.ndim)
         return shard_map(body, mesh=mesh,
                          in_specs=(hspec, wspec, lspec, lspec, lspec),
-                         out_specs=(hspec, wspec), check_rep=False)(
+                         out_specs=(hspec, wspec), check_vma=False)(
                              h, w, labels, lse, gl)
 
     @jax.custom_vjp
@@ -1094,7 +1108,7 @@ def _attn_fused(scale: float, spec: MaskSpec, interp: bool, plan, block):
             return body(q, k, v, kl, qs, ks)
         return shard_map(body, mesh=mesh,
                          in_specs=(qspec, qspec, qspec, P(), sspec, sspec),
-                         out_specs=(qspec, lspec), check_rep=False)(
+                         out_specs=(qspec, lspec), check_vma=False)(
                              q, k, v, kl, qs, ks)
 
     def _bwd_parts(q, k, v, kl, qs, ks, out, lse, do):
@@ -1117,7 +1131,7 @@ def _attn_fused(scale: float, spec: MaskSpec, interp: bool, plan, block):
                          in_specs=(qspec, qspec, qspec, P(), sspec, sspec,
                                    qspec, lspec, qspec),
                          out_specs=(qspec, qspec, qspec),
-                         check_rep=False)(q, k, v, kl, qs, ks, out, lse, do)
+                         check_vma=False)(q, k, v, kl, qs, ks, out, lse, do)
 
     @jax.custom_vjp
     def fused(q, k, v, kl, qs, ks):

@@ -100,6 +100,7 @@ def momentum_sumsq(m, g, beta, axis: str = "col", block=DEFAULT_BLOCK,
         input_output_aliases={0: 0},
         scratch_shapes=[scratch],
         interpret=interpret,
+        name="scale_head_momentum",
     )(m, g, beta_arr, gs_arr)
 
 

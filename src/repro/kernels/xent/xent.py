@@ -70,31 +70,56 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..vmem import COMPILER_PARAMS, VMEM_BUDGET_BYTES, tile_bytes
+
 _NEG = -1e30  # finite -inf stand-in: keeps the running max
 #                            NaN-free when a tile (or a whole vocab shard)
 #                            is entirely padding
 
 
+def _vmem_cost(bn: int, bv: int, d: int, el: int, out_el: int,
+               kernel: str) -> int:
+    """Scoped-VMEM bytes of one ``kernel`` ("fwd" | "dh" | "dw") step.
+
+    Blocks count twice (double-buffered in and out), scratch once, and the
+    f32 logit tile four times (it, its iota/mask and the exp/dlogits
+    temporaries). dH and dW also hold an f32 cast and an iota mask of the
+    operand they zero (w for dH, h for dW) next to their f32 accumulator.
+    """
+    h, w = tile_bytes(bn, d, el), tile_bytes(d, bv, el)
+    vec = tile_bytes(bn, 1, 4)          # labels / lse / ll / gl row vectors
+    logits = 4 * tile_bytes(bn, bv, 4)
+    if kernel == "fwd":
+        return 2 * (h + w + 3 * vec) + 3 * vec + logits
+    if kernel == "dh":
+        return (2 * (h + w + 3 * vec + tile_bytes(bn, d, out_el))
+                + tile_bytes(bn, d, 4) + 2 * tile_bytes(d, bv, 4) + logits)
+    return (2 * (h + w + 3 * vec + tile_bytes(d, bv, out_el))
+            + tile_bytes(d, bv, 4) + 2 * tile_bytes(bn, d, 4) + logits)
+
+
 def _pick_blocks(n: int, d: int, v: int, block=None, *, el_bytes: int = 4,
-                 row_acc: bool = False):
+                 out_bytes: int = 4, kernel: str = "fwd"):
     """(bn, bv) tile for one kernel, clamped to the (padded) problem.
 
     The token tile bn is the HBM-reuse lever: w streams through HBM once
-    per token tile (forward/dH), so bn grows until the (bn, D) h block —
-    or, when ``row_acc``, the (bn, D) f32 dH accumulator — reaches ~4 MiB.
-    bv likewise grows until the (D, bv) w tile / f32 dW accumulator
-    reaches ~4 MiB, then shrinks while the (bn, bv) f32 logit tile
-    exceeds ~8 MiB. Caps at 2048 (diminishing reuse returns), floors at
-    the (32, 128) hardware tiling.
+    per token tile (forward/dH), so tiles start at 2048 x 2048 and shrink
+    until :func:`_vmem_cost` fits the scoped-VMEM budget — bv first down
+    to 512, then bn down to 256, then whichever is larger, to the
+    (32, 128) hardware tiling floor.
     """
     if block is not None:
         bn, bv = block
     else:
-        bn = (4 << 20) // max(d * (4 if row_acc else el_bytes), 1)
-        bn = max(32, min(2048, bn // 32 * 32))
-        bv = max(128, min(2048, ((4 << 20) // max(d * 4, 1)) // 128 * 128))
-        while bn * bv * 4 > (8 << 20) and bv > 128:
-            bv //= 2
+        bn = min(2048, -(-n // 32) * 32)
+        bv = min(2048, -(-v // 128) * 128)
+        fits = lambda bn, bv: _vmem_cost(bn, bv, d, el_bytes, out_bytes,
+                                         kernel) <= VMEM_BUDGET_BYTES
+        while not fits(bn, bv) and (bn > 32 or bv > 128):
+            if bv > 512 or (bn <= 256 and bv > 128 and bv >= bn):
+                bv //= 2
+            else:
+                bn = max(32, bn // 2 // 32 * 32)
     bn = min(bn, -(-n // 32) * 32)
     bv = min(bv, -(-v // 128) * 128)
     return bn, bv
@@ -207,6 +232,8 @@ def xent_fwd(h, w, labels, *, vocab_size: int, col_offset=0, block=None,
                    jax.ShapeDtypeStruct((n, 1), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bn, 1), jnp.float32)] * 3,
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
+        name="xent_fwd",
     )(h, w, labels.reshape(n, 1), off)
     return lse[:, 0], ll[:, 0]
 
@@ -264,7 +291,8 @@ def xent_bwd_dh(h, w, labels, lse, gl, *, vocab_size: int, col_offset=0,
     n, d = h.shape
     v = w.shape[0] if transposed else w.shape[1]
     bn, bv = _pick_blocks(n, d, v, block, el_bytes=h.dtype.itemsize,
-                          row_acc=True)
+                          out_bytes=jnp.dtype(out_dtype).itemsize,
+                          kernel="dh")
     grid = (pl.cdiv(n, bn), pl.cdiv(v, bv))
     off = jnp.asarray(col_offset, jnp.int32).reshape(1, 1)
     tok = pl.BlockSpec((bn, 1), lambda i, j: (i, 0))
@@ -281,6 +309,8 @@ def xent_bwd_dh(h, w, labels, lse, gl, *, vocab_size: int, col_offset=0,
         out_shape=jax.ShapeDtypeStruct((n, d), out_dtype),
         scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32)],
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
+        name="xent_bwd_dh",
     )(h, w, labels.reshape(n, 1), lse.reshape(n, 1), gl.reshape(n, 1), off)
 
 
@@ -338,7 +368,9 @@ def xent_bwd_dw(h, w, labels, lse, gl, *, vocab_size: int, col_offset=0,
     """
     n, d = h.shape
     v = w.shape[0] if transposed else w.shape[1]
-    bn, bv = _pick_blocks(n, d, v, block, el_bytes=h.dtype.itemsize)
+    bn, bv = _pick_blocks(n, d, v, block, el_bytes=h.dtype.itemsize,
+                          out_bytes=jnp.dtype(out_dtype).itemsize,
+                          kernel="dw")
     grid = (pl.cdiv(v, bv), pl.cdiv(n, bn))
     off = jnp.asarray(col_offset, jnp.int32).reshape(1, 1)
     tok = pl.BlockSpec((bn, 1), lambda j, i: (i, 0))
@@ -359,4 +391,6 @@ def xent_bwd_dw(h, w, labels, lse, gl, *, vocab_size: int, col_offset=0,
         scratch_shapes=[pltpu.VMEM((bv, d) if transposed else (d, bv),
                                    jnp.float32)],
         interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
+        name="xent_bwd_dw",
     )(w, h, labels.reshape(n, 1), lse.reshape(n, 1), gl.reshape(n, 1), off)

@@ -127,14 +127,14 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         state_abs = TrainState(jax.ShapeDtypeStruct((), jnp.int32),
                                params_abs, opt_abs)
         state_sh = TrainState(rep, params_sh, opt_sh)
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(step, in_shardings=(state_sh, specs_sh),
                              out_shardings=(state_sh, None),
                              donate_argnums=(0,))
             lowered = jitted.lower(state_abs, specs)
     elif shape.kind == "prefill":
         step = make_prefill_step(cfg, max_seq=shape.seq_len, rules=rules)
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(step, in_shardings=(params_sh, specs_sh["tokens"])
                              if cfg.family != "vlm" else
                              (params_sh, specs_sh["tokens"],
@@ -153,7 +153,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         st_abs = ServeState(cache_abs, jax.ShapeDtypeStruct((), jnp.int32))
         st_sh = ServeState(cache_sh, rep)
         step = make_decode_step(cfg, rules=rules)
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(step, in_shardings=(params_sh, st_sh,
                                                  specs_sh["tokens"]),
                              out_shardings=(st_sh, None),

@@ -122,6 +122,26 @@ def extract_memory(compiled) -> dict:
     return out
 
 
+_KERNEL_RE = re.compile(r'op_name="(?:[^"]*/)?([^"/]+)/pallas_call"')
+
+
+def pallas_kernels(hlo_text: str) -> Dict[str, int]:
+    """{kernel name: count} of the compiled Pallas calls in a TPU program.
+
+    Each kernel is a ``tpu_custom_call`` whose op metadata ends in
+    ``<name>/pallas_call`` (the ``name=`` every kernel under
+    ``repro.kernels`` passes). Off the TPU the kernels are interpreted and
+    none appear.
+    """
+    out: Dict[str, int] = {}
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            m = _KERNEL_RE.search(line)
+            name = m.group(1) if m else "unnamed"
+            out[name] = out.get(name, 0) + 1
+    return out
+
+
 def roofline(cost: dict, coll: CollectiveStats, *, model_flops: float,
              n_chips: int, hw: Optional[dict] = None) -> dict:
     """The three roofline terms (seconds) + bottleneck + usefulness ratio.

@@ -12,13 +12,13 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
@@ -38,7 +38,8 @@ def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
             f"got remainder {n % data if data >= 1 else data}")
     model = max(1, min(model, n // data))
     devs = np.array(jax.devices()[: data * model]).reshape(data, model)
-    return Mesh(devs, ("data", "model"))
+    return Mesh(devs, ("data", "model"),
+                axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 # TPU v5e hardware constants for the roofline model.

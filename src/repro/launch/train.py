@@ -1,8 +1,15 @@
 """Training driver CLI.
 
-Runs a real training loop on whatever devices exist (CPU here, a TPU slice
-in production), with sharding from the same rules table the dry-run uses,
+Runs a real training loop on the devices JAX finds — one TPU chip or a
+four-chip host, or the CPU, where the tests run it with the Pallas kernels
+interpreted — with parameters initialised sharded by the same rules table
+the dry-run uses, each batch placed along the mesh's batch axes,
 deterministic resumable data, periodic checkpointing and auto-resume.
+
+Compile cache: on a TPU, ``main`` keeps JAX's persistent compilation cache
+in ``<repo root>/.jax_cache`` — a fixed path, so a later run finds it —
+unless ``JAX_COMPILATION_CACHE_DIR`` is set, in which case JAX keeps it
+there and nothing here overrides it.
 
 Resilience (PR 8): the step runs under the in-jit anomaly guard
 (:mod:`repro.training.resilience`) — non-finite loss/grad-norm or a loss
@@ -20,7 +27,9 @@ per process — never cross-host-written) behind a non-blocking background
 logger; records at ``--metrics-every`` cadence carry loss/norm metrics,
 step-time breakdown (data wait / blocked step / checkpoint IO), tokens/s,
 per-device memory where the backend reports it, and kernel-fallback
-*deltas* (``dispatch.fallback_delta``). ``--stats-every K`` weaves the
+*deltas* (``dispatch.fallback_delta``); the run ends with a
+``step_program`` record naming the Pallas kernels compiled into the step
+and its ``memory_analysis()``. ``--stats-every K`` weaves the
 in-jit per-layer-group statistics collector into the step (the paper's
 Fig. 4/10 quantities live — see :mod:`repro.obs.stats`); ``--profile-steps
 A:B`` wraps those steps in ``jax.profiler`` traces. Console lines are
@@ -39,6 +48,7 @@ import argparse
 import os
 import signal
 import time
+from pathlib import Path
 
 import jax
 
@@ -47,13 +57,36 @@ from repro.configs import get_arch
 from repro.core import linear_warmup_cosine, make_optimizer
 from repro.data import make_dataset
 from repro.kernels import dispatch
+from repro.launch.hlo_analysis import extract_memory, pallas_kernels
 from repro.launch.mesh import make_host_mesh
-from repro.models import init_params
-from repro.models.sharding import Rules
+from repro.models import init_params, param_logical_axes, param_shapes
+from repro.models.sharding import Rules, tree_shardings
 from repro.obs import (JSONLSink, MetricsLogger, ProfileWindow, StatsPolicy,
                        StepTimer, device_memory, split_stats, trace_span)
 from repro.training import (GuardPolicy, init_guard_state, init_state,
                             make_train_step, resolve_plan)
+
+
+JAX_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> None:
+    """Keep compiled TPU programs in ``JAX_CACHE_DIR`` across runs.
+
+    Leaves the cache to JAX when ``JAX_COMPILATION_CACHE_DIR`` is set, and
+    sets none off the TPU (the CPU test runs keep no cache).
+    """
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+            or jax.default_backend() != "tpu":
+        return
+    jax.config.update("jax_compilation_cache_dir", str(JAX_CACHE_DIR))
+
+
+def place_batch(batch: dict, mesh, rules: Rules) -> dict:
+    """Each host batch leaf on the mesh, split along its batch axes."""
+    return {k: jax.device_put(v, rules.sharding(
+        ("act_batch",) + (None,) * (v.ndim - 1), mesh, v.shape))
+        for k, v in batch.items()}
 
 
 def build(args, lr_scale: float = 1.0):
@@ -154,6 +187,7 @@ def main(argv=None):
                          "restore, so an unbounded loop would never "
                          "terminate)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     guard = None if args.no_guard else GuardPolicy(
         spike_factor=args.spike_factor, spike_warmup=args.spike_warmup,
@@ -196,16 +230,14 @@ def main(argv=None):
                pack_documents=bool(args.pack_documents),
                tie_embeddings=bool(cfg.tie_embeddings))
 
-    params = init_params(jax.random.PRNGKey(args.seed), cfg)
-    if n_dev > 1:
-        # place params per the rules table so the fused optimizer runs
-        # sharded from step 0 (its kernels psum norm reductions over the
-        # mesh — see repro.kernels.dispatch)
-        from repro.models import param_logical_axes
-        from repro.models.sharding import tree_shardings
-        params = jax.device_put(
-            params, tree_shardings(param_logical_axes(cfg), mesh, rules,
-                                   params))
+    # params are born sharded per the rules table (never whole on one
+    # device), so the fused optimizer runs sharded from step 0 (its kernels
+    # psum norm reductions over the mesh — see repro.kernels.dispatch)
+    params = jax.jit(
+        lambda key: init_params(key, cfg),
+        out_shardings=tree_shardings(param_logical_axes(cfg), mesh, rules,
+                                     param_shapes(cfg)),
+    )(jax.random.PRNGKey(args.seed))
     state = init_state(params, tx, guard=guard is not None)
     start_step = 0
     if args.resume == "auto" and args.ckpt_dir:
@@ -278,7 +310,7 @@ def main(argv=None):
             if profile is not None:
                 profile.maybe_start(step)
             with timer.section("data"), trace_span("data_wait"):
-                batch = ds.host_batch_at(step)
+                batch = place_batch(ds.host_batch_at(step), mesh, rules)
             with timer.section("step"), trace_span("train_step"):
                 state, metrics = step_fn(state, batch)
             rollback_flag = False
@@ -357,6 +389,13 @@ def main(argv=None):
                            step=step)
         if args.log_dir and done_steps and step != last_emitted:
             emit_record(step, eff_tokens / max(time.time() - t0, 1e-9))
+        if args.log_dir and done_steps:
+            # the program the steps ran: same arguments, so the jit's own
+            # compile is reused rather than repeated
+            compiled = step_fn.lower(state, batch).compile()
+            logger.log("step_program", step,
+                       kernels=pallas_kernels(compiled.as_text()),
+                       **extract_memory(compiled))
         logger.log("run_end", step,
                    reason="sigterm" if stop["sigterm"] else "done",
                    loss=metrics["loss"], rollbacks=rollbacks,

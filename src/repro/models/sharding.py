@@ -139,21 +139,18 @@ def spec_mesh_axes(spec: P, ndim: int) -> Tuple[Tuple[str, ...], ...]:
 
 
 def shard(x, logical_axes, rules: Rules, mesh: Optional[Mesh] = None):
-    """Annotate an activation with its logical sharding (no-op off-mesh)."""
-    mesh = mesh or _current_mesh()
-    if mesh is None or mesh.empty or len(mesh.devices.flat) == 1:
+    """Annotate an activation with its logical sharding (no-op off-mesh).
+
+    Without an explicit ``mesh`` the mesh the caller traces under is used:
+    ``jax.set_mesh`` outside jit, or ``jax.sharding.use_abstract_mesh``
+    inside it (the trainer's step does the latter with its own mesh).
+    """
+    if mesh is None:
+        mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
         return x
     spec = rules.spec(logical_axes, mesh, x.shape)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-
-
-def _current_mesh() -> Optional[Mesh]:
-    try:
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        return m if m is not None and not m.empty else None
-    except Exception:
-        return None
 
 
 def tree_shardings(logical_tree, mesh: Mesh, rules: Rules, shape_tree=None):

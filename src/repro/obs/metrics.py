@@ -19,7 +19,7 @@ Record grammar (v1)
 -------------------
 Required keys on every record: ``schema`` (str, ``repro_metrics/v1``),
 ``kind`` (str: ``train_step`` | ``serve`` | ``event`` | ``run_header`` |
-``run_end`` | free-form), ``host`` (int process index), ``step`` (int),
+``step_program`` | ``run_end`` | free-form), ``host`` (int process index), ``step`` (int),
 ``t`` (float unix seconds). All other keys are metric fields: numbers
 (finite or ``null``), strings, booleans, or flat lists/dicts thereof.
 :func:`validate_record` / :func:`validate_jsonl` enforce exactly this and

@@ -8,8 +8,8 @@ deltas, matching the dispatch fallback-delta semantics.
 
 :func:`device_memory` reads ``jax.local_devices()[i].memory_stats()``
 where the backend provides it (TPU/GPU; CPU returns nothing) and reports
-live/peak bytes per local device plus totals. Failures are swallowed —
-memory accounting must never take down a training run.
+live/peak bytes per local device plus totals. A TPU that reports no
+stats raises: every TPU has them, so their absence is a fault.
 """
 from __future__ import annotations
 
@@ -59,20 +59,16 @@ def device_memory() -> dict:
 
     Keys: ``mem/dev<i>/bytes_in_use``, ``mem/dev<i>/peak_bytes`` plus
     ``mem/total_bytes_in_use`` / ``mem/total_peak_bytes``. Empty dict on
-    backends without ``memory_stats`` (host CPU).
+    backends without ``memory_stats`` (host CPU). A TPU always reports
+    them, so there a missing report raises instead of logging nothing.
     """
     out: dict = {}
     total_live = total_peak = 0
-    try:
-        devices = jax.local_devices()
-    except Exception:
-        return out
-    for i, d in enumerate(devices):
-        try:
-            ms = d.memory_stats()
-        except Exception:
-            ms = None
+    for i, d in enumerate(jax.local_devices()):
+        ms = d.memory_stats()
         if not ms:
+            if d.platform == "tpu":
+                raise RuntimeError(f"{d} reports no memory_stats()")
             continue
         live = int(ms.get("bytes_in_use", 0))
         peak = int(ms.get("peak_bytes_in_use", live))

@@ -191,6 +191,14 @@ def make_train_step(cfg, tx: GradientTransformation, grad_accum: int = 1,
         return loss, metrics, grads
 
     def train_step(state: TrainState, batch: dict):
+        if mesh is None:
+            return _train_step(state, batch)
+        # the activation constraints (models.sharding.shard) read the mesh
+        # the step is traced under
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return _train_step(state, batch)
+
+    def _train_step(state: TrainState, batch: dict):
         if guard is not None and state.guard is None:
             raise ValueError(
                 "make_train_step(guard=...) needs a guard-carrying state: "

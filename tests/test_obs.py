@@ -326,6 +326,32 @@ def test_cli_writes_schema_valid_jsonl_with_stats(tmp_path, capsys):
             r["stats/hidden/colnorm_disp"]
     assert all("time/step_s" in r and "tokens_per_s" in r for r in steps)
     assert recs[-1]["reason"] == "done"
+    # the step program: no compiled Pallas kernels off the TPU (interpret)
+    prog = [r for r in recs if r["kind"] == "step_program"]
+    assert len(prog) == 1 and prog[0]["kernels"] == {}, prog
+    assert prog[0]["argument_size_in_bytes"] > 0
+
+
+class _Dev:
+    def __init__(self, platform, stats):
+        self.platform, self._stats = platform, stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+def test_device_memory_reports_and_raises_on_silent_tpu(monkeypatch):
+    from repro.obs import device_memory
+    monkeypatch.setattr(jax, "local_devices", lambda: [_Dev("cpu", None)])
+    assert device_memory() == {}
+    monkeypatch.setattr(jax, "local_devices", lambda: [_Dev(
+        "tpu", {"bytes_in_use": 5, "peak_bytes_in_use": 7})])
+    assert device_memory() == {
+        "mem/dev0/bytes_in_use": 5, "mem/dev0/peak_bytes": 7,
+        "mem/total_bytes_in_use": 5, "mem/total_peak_bytes": 7}
+    monkeypatch.setattr(jax, "local_devices", lambda: [_Dev("tpu", None)])
+    with pytest.raises(RuntimeError, match="no memory_stats"):
+        device_memory()
 
 
 def test_cli_sigterm_flushes_metrics_tail(tmp_path):

@@ -403,6 +403,24 @@ def test_dispatch_fault_wildcard_hits_every_op(monkeypatch):
     assert dispatch.fallback_counts().get("normalize") == 1
 
 
+def test_real_kernel_failure_raises_on_tpu(monkeypatch):
+    """Off the TPU a failing kernel route degrades; on a TPU only an
+    injected dispatch_fail does — a real failure stops the run."""
+    def boom():
+        raise RuntimeError("kernel does not lower")
+
+    ref = lambda: "ref"
+    with pytest.warns(UserWarning, match="degrading"):
+        assert dispatch._guarded("normalize", boom, ref) == "ref"
+    monkeypatch.setattr(dispatch, "backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="does not lower"):
+        dispatch._guarded("normalize", boom, ref)
+    monkeypatch.setenv(faults.ENV_VAR, "dispatch_fail@normalize")
+    with pytest.warns(UserWarning, match="degrading"):
+        assert dispatch._guarded("normalize", lambda: "kernel", ref) == "ref"
+    assert dispatch.fallback_counts() == {"normalize": 2}
+
+
 def test_guarded_train_step_survives_dispatch_fault(tiny, monkeypatch):
     """The full stack degrades gracefully: a train step whose optimizer
     kernels are forced to fail still trains (reference path), finite."""

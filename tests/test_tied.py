@@ -351,7 +351,8 @@ from repro.kernels import dispatch
 from repro.kernels.xent import ref as xref
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(data=4, model=2)  # Auto axes, as the trainer's
 B, S, D, V, VS = 8, 16, 32, 256, 200
 ks = jax.random.split(jax.random.PRNGKey(0), 3)
 for dtype in (jnp.float32, jnp.bfloat16):
@@ -436,7 +437,7 @@ ds = make_dataset(cfg, seq_len=32, global_batch=8)
 batch = ds.host_batch_at(0)
 s1, m1 = jax.jit(make_train_step(cfg, tx))(init_state(params, tx), batch)
 step_m = make_train_step(cfg, tx, mesh=mesh)
-with mesh:
+with jax.set_mesh(mesh):
     s2, m2 = jax.jit(step_m)(init_state(params, tx), batch)
 np.testing.assert_allclose(float(m1["loss"]), float(m2["loss"]), rtol=1e-5)
 for a, b in zip(jax.tree_util.tree_leaves(s1.params),

@@ -307,7 +307,8 @@ from repro.kernels import dispatch
 from repro.kernels.xent import ref as xref
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(data=4, model=2)  # Auto axes, as the trainer's
 B, S, D, V, VS = 8, 16, 32, 256, 200
 ks = jax.random.split(jax.random.PRNGKey(0), 3)
 for dtype in (jnp.float32, jnp.bfloat16):
