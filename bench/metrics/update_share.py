@@ -1,0 +1,10 @@
+"""Device time of operations under the ``optimizer_update`` scope over
+the device's busy time."""
+from bench import trace as tr
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    t = tr.scope_ns(run.trace, run.scopes, "optimizer_update")
+    return 100.0 * t / tr.busy_ns(run.trace) if t else None
