@@ -51,6 +51,7 @@ import time
 from pathlib import Path
 
 import jax
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.checkpoint import restore_latest, save, save_async
 from repro.configs import get_arch
@@ -62,7 +63,7 @@ from repro.launch.mesh import make_host_mesh
 from repro.models import init_params, param_logical_axes, param_shapes
 from repro.models.sharding import Rules, tree_shardings
 from repro.obs import (JSONLSink, MetricsLogger, ProfileWindow, StatsPolicy,
-                       StepTimer, device_memory, split_stats, trace_span)
+                       StepTimer, device_memory, split_stats)
 from repro.training import (GuardPolicy, init_guard_state, init_state,
                             make_train_step, resolve_plan)
 
@@ -305,78 +306,89 @@ def main(argv=None):
         rec["rollbacks"] = rollbacks
         logger.log("train_step", step, rec)
 
+    def roll_back(step, state, skipped):
+        """The guard flagged an unrecoverable streak at ``step``; the host
+        takes the action jit cannot: restore + LR cut + retrace. Returns
+        the step to run next and its state."""
+        nonlocal lr_scale, rollbacks, step_fn
+        lr_scale *= args.rollback_lr_cut
+        rollbacks += 1
+        logger.log("event", step, event="rollback", rollbacks=rollbacks,
+                   lr_scale=lr_scale, skipped=skipped)
+        logger.flush()     # the tail of a sick run must hit disk
+        if rollbacks > args.max_rollbacks:
+            raise RuntimeError(
+                f"giving up after {args.max_rollbacks} rollbacks: the run "
+                f"keeps hitting {args.max_bad_steps} consecutive bad steps")
+        got = restore_latest(args.ckpt_dir, state) if args.ckpt_dir else None
+        if got is not None:
+            state, step = got
+            logger.console(f"rollback #{rollbacks}: restored step {step}, "
+                           f"peak lr x{lr_scale:g}", step=step)
+        else:
+            # nothing to roll back to: reset the streak and push on with
+            # the cut LR (the guard keeps skipping bad steps)
+            step += 1
+            logger.console(f"rollback #{rollbacks}: no checkpoint in "
+                           f"{args.ckpt_dir or '<none>'}; continuing with "
+                           f"peak lr x{lr_scale:g}", step=step)
+        _, tx = build(args, lr_scale)
+        step_fn = make_step(tx)
+        return step, state._replace(guard=init_guard_state())
+
     try:
         while step < args.steps and not stop["sigterm"]:
             if profile is not None:
                 profile.maybe_start(step)
-            with timer.section("data"), trace_span("data_wait"):
-                batch = place_batch(ds.host_batch_at(step), mesh, rules)
-            with timer.section("step"), trace_span("train_step"):
-                state, metrics = step_fn(state, batch)
-            rollback_flag = False
-            if guard is not None:
-                with timer.section("sync"):
-                    rollback_flag = bool(float(metrics["rollback"]))
-            if profile is not None:
-                profile.maybe_stop(step)
-            if rollback_flag:
-                # in-jit code flagged an unrecoverable streak; the host
-                # takes the action jit cannot: restore + LR cut + retrace
-                lr_scale *= args.rollback_lr_cut
-                rollbacks += 1
-                logger.log("event", step, event="rollback",
-                           rollbacks=rollbacks, lr_scale=lr_scale,
-                           skipped=metrics["skipped"])
-                logger.flush()     # the tail of a sick run must hit disk
-                if rollbacks > args.max_rollbacks:
-                    raise RuntimeError(
-                        f"giving up after {args.max_rollbacks} rollbacks: "
-                        f"the run keeps hitting {args.max_bad_steps} "
-                        f"consecutive bad steps")
-                got = restore_latest(args.ckpt_dir, state) \
-                    if args.ckpt_dir else None
-                if got is not None:
-                    state, step = got
-                    logger.console(f"rollback #{rollbacks}: restored step "
-                                   f"{step}, peak lr x{lr_scale:g}",
-                                   step=step)
+            turn = step
+            # one numbered step in the profiler per loop turn, so every
+            # idle gap of the device lands in a step
+            with StepTraceAnnotation("train", step_num=turn):
+                with timer.section("data"), TraceAnnotation("data_wait"):
+                    batch = place_batch(ds.host_batch_at(step), mesh, rules)
+                # "step" is blocked step time: the call through the read of
+                # the guard's rollback flag, which waits for the step's
+                # outputs (with --no-guard, nothing is read: dispatch time)
+                with timer.section("step"):
+                    with TraceAnnotation("train_step"):
+                        state, metrics = step_fn(state, batch)
+                    rollback_flag = False
+                    if guard is not None:
+                        with TraceAnnotation("sync"):
+                            rollback_flag = bool(float(metrics["rollback"]))
+                if rollback_flag:
+                    step, state = roll_back(step, state, metrics["skipped"])
                 else:
-                    # nothing to roll back to: reset the streak and push on
-                    # with the cut LR (the guard keeps skipping bad steps)
                     step += 1
-                    logger.console(f"rollback #{rollbacks}: no checkpoint "
-                                   f"in {args.ckpt_dir or '<none>'}; "
-                                   f"continuing with peak lr x{lr_scale:g}",
-                                   step=step)
-                state = state._replace(guard=init_guard_state())
-                _, tx = build(args, lr_scale)
-                step_fn = make_step(tx)
-                continue
-            step += 1
-            done_steps += 1
-            eff_tokens += float(metrics.get("weight", tokens_per_step)) \
-                if args.pack_documents else tokens_per_step
-            tput = eff_tokens / max(time.time() - t0, 1e-9)
-            if step % args.log_every == 0 or done_steps == 1:
-                line = (f"step {step:6d} loss {float(metrics['loss']):.4f} "
-                        f"|g| {float(metrics['grad_norm']):.3f} "
-                        f"tok/s {tput:,.0f}")
-                if guard is not None:
-                    line += (f" skipped {int(metrics['skipped'])}"
-                             f" rollbacks {rollbacks}")
-                fb = dispatch.fallback_counts()
-                if fb:
-                    line += f" kernel-fallbacks {sum(fb.values())}"
-                logger.console(line, step=step, raw=True)
-            if args.log_dir and (step % args.metrics_every == 0
-                                 or done_steps == 1):
-                emit_record(step, tput)
-            if args.ckpt_dir and step % args.ckpt_every == 0:
-                with timer.section("ckpt"), trace_span("checkpoint"):
-                    if pending is not None:
-                        pending.wait()   # one checkpoint in flight at a time
-                    pending = save_async(args.ckpt_dir, step, state)
-        with timer.section("ckpt"), trace_span("checkpoint"):
+                    done_steps += 1
+                    eff_tokens += (
+                        float(metrics.get("weight", tokens_per_step))
+                        if args.pack_documents else tokens_per_step)
+                    tput = eff_tokens / max(time.time() - t0, 1e-9)
+                    if step % args.log_every == 0 or done_steps == 1:
+                        line = (f"step {step:6d} loss "
+                                f"{float(metrics['loss']):.4f} "
+                                f"|g| {float(metrics['grad_norm']):.3f} "
+                                f"tok/s {tput:,.0f}")
+                        if guard is not None:
+                            line += (f" skipped {int(metrics['skipped'])}"
+                                     f" rollbacks {rollbacks}")
+                        fb = dispatch.fallback_counts()
+                        if fb:
+                            line += f" kernel-fallbacks {sum(fb.values())}"
+                        logger.console(line, step=step, raw=True)
+                    if args.log_dir and (step % args.metrics_every == 0
+                                         or done_steps == 1):
+                        emit_record(step, tput)
+                    if args.ckpt_dir and step % args.ckpt_every == 0:
+                        with timer.section("ckpt"), \
+                                TraceAnnotation("checkpoint"):
+                            if pending is not None:
+                                pending.wait()   # one checkpoint in flight
+                            pending = save_async(args.ckpt_dir, step, state)
+            if profile is not None:
+                profile.maybe_stop(turn)
+        with timer.section("ckpt"), TraceAnnotation("checkpoint"):
             if pending is not None:
                 pending.wait()
             if args.ckpt_dir:

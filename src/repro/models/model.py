@@ -153,37 +153,43 @@ def forward(params, cfg: ModelConfig, tokens, *, image_embeds=None,
     ``positions`` is None the usual 0..S-1 (or cache-offset) ramp is used.
     """
     rules = rules or Rules(cfg.rule_overrides)
-    ew = params["tok_embed"]["w"]
-    if cfg.family == "audio":
-        # tokens (B, n_codebooks, S): sum codebook embeddings
-        x = sum(jnp.take(ew[c], tokens[:, c], axis=0)
-                for c in range(cfg.n_codebooks))
-    else:
-        x = jnp.take(ew, tokens, axis=0)
-    x = shard(x, ("act_batch", "act_seq", "act_embed"), rules)
-
-    S = x.shape[1]
+    S = tokens.shape[-1]
     if positions is None:
         if mode == "decode":
             positions = cache_index + jnp.arange(S)
         else:
             positions = jnp.arange(S)
-    if cfg.pos_embed == "learned":
-        x = x + jnp.take(params["pos_embed"]["w"], positions, axis=0)
+    # one named scope per layer (embed / layers / final_norm here, one per
+    # sub-layer kind in transformer._apply_sub, head in loss_fn): the
+    # backward pass and the rematerialised forward inherit the names, so a
+    # device trace charges each op to its layer
+    with jax.named_scope("embed"):
+        ew = params["tok_embed"]["w"]
+        if cfg.family == "audio":
+            # tokens (B, n_codebooks, S): sum codebook embeddings
+            x = sum(jnp.take(ew[c], tokens[:, c], axis=0)
+                    for c in range(cfg.n_codebooks))
+        else:
+            x = jnp.take(ew, tokens, axis=0)
+        x = shard(x, ("act_batch", "act_seq", "act_embed"), rules)
+        if cfg.pos_embed == "learned":
+            x = x + jnp.take(params["pos_embed"]["w"], positions, axis=0)
 
     new_cache = {} if cache is not None else None
     aux = jnp.zeros((), jnp.float32)
     for i, (kind, n) in enumerate(cfg.segments):
         name = f"seg{i}_{kind}"
         seg_cache = cache[name] if cache is not None else None
-        x, seg_cache, seg_aux = T.apply_segment(
-            kind, n, cfg, params["segments"][name], x, positions, rules,
-            mode, seg_cache, cache_index, image_embeds, mesh=mesh,
-            segment_ids=segment_ids)
+        with jax.named_scope("layers"):
+            x, seg_cache, seg_aux = T.apply_segment(
+                kind, n, cfg, params["segments"][name], x, positions, rules,
+                mode, seg_cache, cache_index, image_embeds, mesh=mesh,
+                segment_ids=segment_ids)
         if new_cache is not None:
             new_cache[name] = seg_cache
         aux = aux + seg_aux
-    x = L.rmsnorm(x, params["final_norm"]["s"], cfg.rms_eps)
+    with jax.named_scope("final_norm"):
+        x = L.rmsnorm(x, params["final_norm"]["s"], cfg.rms_eps)
     return x, new_cache, aux
 
 
@@ -398,7 +404,9 @@ def loss_fn(params, cfg: ModelConfig, batch: dict, aux_coef: float = 0.01,
                              mode="train", rules=rules, mesh=mesh,
                              positions=batch.get("positions"),
                              segment_ids=batch.get("segment_ids"))
-    loss, weight = lm_loss(params, cfg, hidden, batch["labels"], rules=rules,
-                           mesh=mesh, weights=batch.get("loss_weights"))
+    with jax.named_scope("head"):
+        loss, weight = lm_loss(params, cfg, hidden, batch["labels"],
+                               rules=rules, mesh=mesh,
+                               weights=batch.get("loss_weights"))
     total = loss + aux_coef * aux
     return total, {"loss": loss, "aux": aux, "weight": weight}

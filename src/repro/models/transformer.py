@@ -54,33 +54,38 @@ def _apply_sub(sub: str, p: dict, cfg: ModelConfig, x, positions, rules: Rules,
     *self*-attention subs only (cross-attention keys are not packed and
     mamba's sequence mixing has no segment mask — packed batches are an
     attention-family format).
+
+    The whole sub-layer (pre-norm, layer, residual add) runs under one
+    named scope, the sub-layer kind (``attn``, ``cross``, ``mlp``, ``moe``,
+    ``mamba``), so every architecture's trace is named the same way.
     """
-    h = L.rmsnorm(x, p["norm"], cfg.rms_eps)
-    aux = jnp.zeros((), jnp.float32)
-    if sub == "attn":
-        if cfg.attention_kind == "mla":
-            y, cache = L.apply_mla_attention(p, cfg, h, positions, rules,
+    with jax.named_scope(sub):
+        h = L.rmsnorm(x, p["norm"], cfg.rms_eps)
+        aux = jnp.zeros((), jnp.float32)
+        if sub == "attn":
+            if cfg.attention_kind == "mla":
+                y, cache = L.apply_mla_attention(p, cfg, h, positions, rules,
+                                                 mode, cache, cache_index,
+                                                 mesh=mesh,
+                                                 segment_ids=segment_ids)
+            else:
+                y, cache = L.apply_attention(p, cfg, h, positions, rules,
                                              mode, cache, cache_index,
                                              mesh=mesh,
                                              segment_ids=segment_ids)
+        elif sub == "cross":
+            y, _ = L.apply_attention(p, cfg, h, positions, rules, mode="train",
+                                     kv_source=image_embeds, causal=False,
+                                     mesh=mesh)
+        elif sub == "mlp":
+            y = L.apply_mlp(p, cfg, h, rules)
+        elif sub == "moe":
+            y, aux = L.apply_moe(p, cfg, h, rules)
+        elif sub == "mamba":
+            y, cache = L.apply_mamba(p, cfg, h, rules, mode, cache)
         else:
-            y, cache = L.apply_attention(p, cfg, h, positions, rules,
-                                         mode, cache, cache_index,
-                                         mesh=mesh,
-                                         segment_ids=segment_ids)
-    elif sub == "cross":
-        y, _ = L.apply_attention(p, cfg, h, positions, rules, mode="train",
-                                 kv_source=image_embeds, causal=False,
-                                 mesh=mesh)
-    elif sub == "mlp":
-        y = L.apply_mlp(p, cfg, h, rules)
-    elif sub == "moe":
-        y, aux = L.apply_moe(p, cfg, h, rules)
-    elif sub == "mamba":
-        y, cache = L.apply_mamba(p, cfg, h, rules, mode, cache)
-    else:
-        raise ValueError(sub)
-    return x + y.astype(x.dtype), cache, aux
+            raise ValueError(sub)
+        return x + y.astype(x.dtype), cache, aux
 
 
 # ------------------------------------------------------------- super-blocks

@@ -10,9 +10,6 @@ disk). Sinks:
     every record schema-versioned (``schema = "repro_metrics/v1"``) and
     carrying ``kind`` / ``host`` / ``step`` / ``t``; non-finite floats are
     serialized as ``null`` so every line is strict JSON.
-  * :class:`CSVSink`  — convenience tabular view. The header is fixed by
-    the first record written; later records fill known columns (missing
-    -> empty, unknown -> dropped). Use JSONL for anything programmatic.
   * :class:`MemorySink` — in-process list of records, for tests.
 
 Record grammar (v1)
@@ -159,42 +156,6 @@ class JSONLSink:
 
     def write(self, rec: dict) -> None:
         self._f.write(json.dumps(rec, separators=(",", ":")) + "\n")
-
-    def flush(self) -> None:
-        self._f.flush()
-
-    def close(self) -> None:
-        self._f.flush()
-        self._f.close()
-
-
-class CSVSink:
-    """Tabular convenience sink; header fixed by the first record."""
-
-    def __init__(self, path: str):
-        self.path = path
-        d = os.path.dirname(path)
-        if d:
-            os.makedirs(d, exist_ok=True)
-        self._f = open(path, "a", buffering=1 << 16)
-        self._cols: Optional[list] = None
-
-    @staticmethod
-    def _cell(v) -> str:
-        if v is None:
-            return ""
-        s = str(v)
-        if any(c in s for c in ",\"\n"):
-            s = '"' + s.replace('"', '""') + '"'
-        return s
-
-    def write(self, rec: dict) -> None:
-        if self._cols is None:
-            self._cols = list(_REQUIRED) + sorted(
-                k for k in rec if k not in _REQUIRED)
-            self._f.write(",".join(self._cols) + "\n")
-        self._f.write(",".join(self._cell(rec.get(c)) for c in self._cols)
-                      + "\n")
 
     def flush(self) -> None:
         self._f.flush()

@@ -1,4 +1,4 @@
-"""Profiler trace hooks: a step-window driver for ``jax.profiler`` plus
+"""Profiler trace hooks: a step window for ``jax.profiler``, and the
 named spans the trace viewer groups work under.
 
 ``--profile-steps A:B`` on the training CLI parses into a
@@ -6,32 +6,25 @@ named spans the trace viewer groups work under.
 ``maybe_stop(step)`` after each step, so exactly steps ``A..B`` (inclusive,
 0-indexed like the log lines) land in the trace. Spans:
 
-  * in-jit work is annotated with ``jax.named_scope`` inside the trainer
-    (``fwd``, ``optimizer_update``, ``guard``, ``obs_stats``) — those names
-    show up on the compiled op metadata;
-  * host-side phases (checkpoint IO, data wait) wrap in
-    :func:`trace_span`, a ``jax.profiler.TraceAnnotation`` when available
-    and a no-op otherwise — safe to leave on every step.
+  * in-jit work is annotated with ``jax.named_scope`` in the model
+    (``embed``, ``layers``, one per sub-layer kind, ``final_norm``,
+    ``head``) and the trainer (``fwd``, ``clip``, ``optimizer_update``,
+    ``guard``, ``step_metrics``, ``obs_stats``) — those names show up on
+    the compiled op metadata, and the backward pass and recomputed forward
+    inherit them (see README.md for the metric that reads each);
+  * host-side phases of the training loop (``data_wait``, ``train_step``,
+    ``sync``, ``checkpoint``) wrap in ``jax.profiler.TraceAnnotation``, and
+    each loop turn in a numbered ``jax.profiler.StepTraceAnnotation`` —
+    both cost nothing while no trace is being taken, so they stay on
+    every step.
 """
 from __future__ import annotations
 
 import os
 import warnings
-from contextlib import nullcontext
 from typing import Optional
 
 import jax
-
-
-def trace_span(name: str):
-    """Context manager naming a host-side span in the profiler timeline."""
-    ta = getattr(jax.profiler, "TraceAnnotation", None)
-    if ta is None:
-        return nullcontext()
-    try:
-        return ta(name)
-    except Exception:
-        return nullcontext()
 
 
 class ProfileWindow:

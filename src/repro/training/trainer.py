@@ -208,17 +208,19 @@ def make_train_step(cfg, tx: GradientTransformation, grad_accum: int = 1,
         raw_grads = grads   # pre-clip: what the Fig. 4/10 stats measure
         out_metrics = {"loss": loss}
         step_kwargs = dict(up_kwargs)
-        if clip_norm > 0 or norm_metrics or guard is not None:
-            gnorm = global_norm(grads)
-            out_metrics["grad_norm"] = gnorm
-        if clip_norm > 0:
-            scale = jnp.minimum(1.0, clip_norm / (gnorm + 1e-9))
-            if fuse_clip:
-                # folded into the optimizer's gradient read (in-kernel for
-                # fused leaves): no materialized g*scale tree
-                step_kwargs["grad_scale"] = scale
-            else:
-                grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
+        with jax.named_scope("clip"):
+            if clip_norm > 0 or norm_metrics or guard is not None:
+                gnorm = global_norm(grads)
+                out_metrics["grad_norm"] = gnorm
+            if clip_norm > 0:
+                scale = jnp.minimum(1.0, clip_norm / (gnorm + 1e-9))
+                if fuse_clip:
+                    # folded into the optimizer's gradient read (in-kernel
+                    # for fused leaves): no materialized g*scale tree
+                    step_kwargs["grad_scale"] = scale
+                else:
+                    grads = jax.tree_util.tree_map(lambda g: g * scale,
+                                                   grads)
         with jax.named_scope("optimizer_update"):
             if fused_apply:
                 params, opt_state = tx.update_params(
@@ -245,19 +247,21 @@ def make_train_step(cfg, tx: GradientTransformation, grad_accum: int = 1,
             out_metrics["bad_step"] = (~ok).astype(jnp.int32)
             out_metrics["rollback"] = rollback
         if norm_metrics:
-            if fused_apply:
-                # diff in f32: bf16 params round small per-element updates
-                # away when differenced in the param dtype (post-guard, so
-                # a skipped step truthfully reports 0)
-                out_metrics["update_norm"] = global_norm(
-                    jax.tree_util.tree_map(
-                        lambda a, b: (a.astype(jnp.float32)
-                                      - b.astype(jnp.float32)),
-                        params, state.params))
-            else:
-                unorm = global_norm(updates)
-                out_metrics["update_norm"] = (
-                    jnp.where(ok, unorm, 0.0) if guard is not None else unorm)
+            with jax.named_scope("step_metrics"):
+                if fused_apply:
+                    # diff in f32: bf16 params round small per-element
+                    # updates away when differenced in the param dtype
+                    # (post-guard, so a skipped step truthfully reports 0)
+                    out_metrics["update_norm"] = global_norm(
+                        jax.tree_util.tree_map(
+                            lambda a, b: (a.astype(jnp.float32)
+                                          - b.astype(jnp.float32)),
+                            params, state.params))
+                else:
+                    unorm = global_norm(updates)
+                    out_metrics["update_norm"] = (
+                        jnp.where(ok, unorm, 0.0) if guard is not None
+                        else unorm)
         if stats_fn is not None:
             # post-guard tensors: a skipped step truthfully reports a zero
             # update; the collector is read-only, so params/opt_state are

@@ -18,7 +18,7 @@ from repro.core import make_optimizer
 from repro.core.labels import LAYER_GROUPS, layer_group
 from repro.data import make_dataset
 from repro.kernels import dispatch
-from repro.obs import (SCHEMA, CSVSink, JSONLSink, MemorySink, MetricsLogger,
+from repro.obs import (SCHEMA, JSONLSink, MemorySink, MetricsLogger,
                        ProfileWindow, StatsPolicy, StepTimer, jsonable,
                        split_stats, stats_keys, validate_jsonl,
                        validate_record)
@@ -99,19 +99,6 @@ def test_logger_rejects_shadowed_required_key():
     with MetricsLogger([MemorySink()]) as lg:
         with pytest.raises(ValueError, match="shadow"):
             lg.log("x", 0, fields={"step": 7})
-
-
-def test_csv_sink_fixed_header(tmp_path):
-    path = str(tmp_path / "m.csv")
-    with MetricsLogger([CSVSink(path)], host=1) as lg:
-        lg.log("train_step", 1, loss=1.0, extra="a,b")
-        lg.log("train_step", 2, loss=2.0, novel=9)  # unknown col dropped
-    lines = open(path).read().splitlines()
-    header = lines[0].split(",")
-    assert header[:5] == ["schema", "kind", "host", "step", "t"]
-    assert "extra" in header and "novel" not in header
-    assert '"a,b"' in lines[1]
-    assert len(lines) == 3
 
 
 def test_memory_sink_background_flush_cadence():
@@ -330,6 +317,34 @@ def test_cli_writes_schema_valid_jsonl_with_stats(tmp_path, capsys):
     prog = [r for r in recs if r["kind"] == "step_program"]
     assert len(prog) == 1 and prog[0]["kernels"] == {}, prog
     assert prog[0]["argument_size_in_bytes"] > 0
+
+
+def test_cli_profile_window_names_host_spans(tmp_path, capsys):
+    """--profile-steps: the trace's host plane holds the training loop's spans
+    (data_wait, train_step and the guard's rollback read, sync), the names
+    the benchmark's spans use, and one numbered "train" step per turn."""
+    import glob
+    from repro.launch.train import main
+    main(["--arch", "qwen2-7b", "--smoke", "--steps", "3", "--batch", "2",
+          "--seq", "32", "--log-every", "1", "--log-dir", str(tmp_path),
+          "--profile-steps", "1:2"])
+    capsys.readouterr()
+    files = glob.glob(str(tmp_path / "profile" / "**" / "*.xplane.pb"),
+                      recursive=True)
+    assert len(files) == 1, files
+    data = jax.profiler.ProfileData.from_file(files[0])
+    spans, steps = {}, []
+    for plane in data.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in ("data_wait", "train_step", "sync"):
+                    spans[e.name] = spans.get(e.name, 0) + 1
+                elif e.name == "train":
+                    steps.append(dict(e.stats)["step_num"])
+    assert spans == {"data_wait": 2, "train_step": 2, "sync": 2}, spans
+    assert sorted(steps) == [1, 2]
 
 
 class _Dev:
