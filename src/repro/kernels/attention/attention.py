@@ -43,6 +43,21 @@ only the filled tiles), or when the two tiles' segment-id *ranges* don't
 overlap (packed documents are contiguous, so segment ids are sorted per
 row and a min/max range test is exact for interior tiles).
 
+Skipped pairs skip their copies too. Each wrapper builds one
+:func:`~repro.kernels.attention.mask.fetch_plan` from its mask operands —
+the same tile test over every pair: for each (row, q tile) the first and
+last live kv tile, for each (row, kv tile) the first and last live q tile
+— and hands it to the ``pallas_call`` as two scalar-prefetch operands.
+The index maps of the streamed blocks (k, v and the kv segment ids in
+forward/dQ; q, dout, lse, delta and the q segment ids in dK/dV) clamp
+their tile into that range, so a dead step asks for the block the step
+before it holds and the pipeline starts no copy. Such a step then holds
+another tile's segment ids, so the tile test, with its segment-id reads
+and reductions, runs only on a step whose clamped index is its own: a
+step outside the range does no work at all. Every live step computes on
+the same blocks as without the plan. :func:`fetch_repeats` counts the
+steps that copy nothing.
+
 Masking mirrors the xent kernels' conventions (out-of-bounds block regions
 are undefined — NaN in interpret mode — and 0*NaN = NaN, so *both*
 operands of every contraction are zeroed on padded positions):
@@ -75,11 +90,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..vmem import COMPILER_PARAMS, VMEM_BUDGET_BYTES, tile_bytes
-from .mask import MaskSpec, mask_spec
+from .mask import MaskSpec, fetch_plan, mask_spec
 
 _NEG = -1e30  # finite -inf stand-in: keeps the running max NaN-free when a
 #               tile (or a whole row) is entirely masked
@@ -150,6 +166,24 @@ def _run_pair(i, j, bq, bk, spec: MaskSpec, kl, qseg, kseg, s_len: int,
     return run
 
 
+def _when_live(own, i, j, bq, bk, spec: MaskSpec, kl, qs_ref, ks_ref,
+               s_len: int, t_len: int):
+    """Decorator: run ``body(qseg, kseg)`` where tile pair (i, j) is live.
+
+    ``own`` says the step holds its own blocks. A step clamped onto a
+    neighbour's block holds that block's segment ids, so only an own step
+    reads them and runs :func:`_run_pair`; every other step does nothing.
+    """
+    def wrap(body):
+        @pl.when(own)
+        def _test():
+            qseg = qs_ref[0] if spec.has_segments else None
+            kseg = ks_ref[0] if spec.has_segments else None
+            pl.when(_run_pair(i, j, bq, bk, spec, kl, qseg, kseg, s_len,
+                              t_len))(lambda: body(qseg, kseg))
+    return wrap
+
+
 def _masks(i, j, bq, bk, spec: MaskSpec, kl, qseg, kseg, s_len: int,
            t_len: int):
     """(col validity, row validity) (bq, bk) masks for one score tile."""
@@ -163,33 +197,85 @@ def _masks(i, j, bq, bk, spec: MaskSpec, kl, qseg, kseg, s_len: int,
     return valid, rows < s_len
 
 
-def _seg_blocks(spec: MaskSpec, segments, B, S, T, bq, bk, H_or_K, *,
-                dkv: bool = False):
+def _seg_blocks(spec: MaskSpec, segments, B, S, T, bq, bk, qmap, kmap):
     """(q_seg array, kv_seg array, q BlockSpec, kv BlockSpec) operand pack.
 
     Segment ids enter as (B, S)/(B, T) int32 and are viewed as
     (B, S, 1)/(B, 1, T) so the query tile blocks to (1, bq, 1) — a column
-    the score-tile mask broadcasts against the key tile's (1, 1, bk) row.
-    Without segments a zero-size dummy pair keeps the pallas arity fixed
-    (one int32 element of traffic, no reads).
+    the score-tile mask broadcasts against the key tile's (1, 1, bk) row;
+    ``qmap``/``kmap`` are their index maps. Without segments a zero-size
+    dummy pair keeps the pallas arity fixed (one int32 element of traffic,
+    no reads).
     """
-    if dkv:
-        qmap = lambda b, j, g, i: (b // H_or_K, i, 0)
-        kmap = lambda b, j, g, i: (b // H_or_K, 0, j)
-        zmap = lambda b, j, g, i: (0, 0, 0)
-    else:
-        qmap = lambda b, i, j: (b // H_or_K, i, 0)
-        kmap = lambda b, i, j: (b // H_or_K, 0, j)
-        zmap = lambda b, i, j: (0, 0, 0)
     if not spec.has_segments:
         dummy = jnp.zeros((1, 1, 1), jnp.int32)
-        return dummy, dummy, pl.BlockSpec((1, 1, 1), zmap), \
-            pl.BlockSpec((1, 1, 1), zmap)
+        zero = pl.BlockSpec((1, 1, 1), lambda *_: (0, 0, 0))
+        return dummy, dummy, zero, zero
     q_seg, kv_seg = segments
     qs = q_seg.astype(jnp.int32).reshape(B, S, 1)
     ks = kv_seg.astype(jnp.int32).reshape(B, 1, T)
     return qs, ks, pl.BlockSpec((1, bq, 1), qmap), pl.BlockSpec((1, 1, bk),
                                                                 kmap)
+
+
+def _clamp(t, lo, hi, row):
+    """Tile index ``t`` held to the live range ``[lo[row], hi[row]]``.
+
+    A step outside the range asks for the block the step before it
+    already holds, so the pipeline copies nothing for it.
+    """
+    return jnp.minimum(jnp.maximum(t, lo[row]), hi[row])
+
+
+def _kv_stream(H: int, G: int, n_q: int):
+    """Index map of the k/v blocks on the (batch*heads, q, kv) grid, the
+    kv tile clamped to the live run of the step's (row, q tile)."""
+    def index(bh, i, j, lo, hi):
+        return (bh // H, (bh % H) // G,
+                _clamp(j, lo, hi, (bh // H) * n_q + i), 0)
+    return index
+
+
+def _q_stream(K: int, G: int, n_k: int):
+    """Index map of the q/dout/lse/delta blocks on the dK/dV grid
+    (batch*kv_heads, kv, group, q), the q tile clamped to the live run of
+    the step's (row, kv tile)."""
+    def index(bk_, j, g, i, lo, hi):
+        return (bk_ // K, (bk_ % K) * G + g,
+                _clamp(i, lo, hi, (bk_ // K) * n_k + j), 0)
+    return index
+
+
+def fetch_repeats(q, k, v, kv_len=None, *, causal: bool = True,
+                  segments=None, block=None) -> dict:
+    """{kernel: (repeats, steps)} for one call of each attention kernel.
+
+    ``repeats`` counts the grid steps whose streamed block (k/v in the
+    forward and dQ kernels, q/dout in dK/dV) has the same index as the
+    step before, so the pipeline copies none; ``steps`` is the grid's size.
+    Takes the operands of :func:`mha_fwd` (arrays or ``ShapeDtypeStruct``)
+    and evaluates the kernels' own index maps over their grids on the host.
+    """
+    B, S, H, hd = q.shape
+    T, K = k.shape[1], k.shape[2]
+    G = H // K
+    spec = mask_spec(S, T, causal=causal, kv_len=kv_len, segments=segments)
+    bq, bk = _pick_tiles(S, T, hd, v.shape[-1], block,
+                         el_bytes=jnp.dtype(q.dtype).itemsize)
+    nq, nk = -(-S // bq), -(-T // bk)
+    plan = [np.asarray(x).reshape(-1) for x in fetch_plan(
+        spec, S, T, bq, bk, batch=B, kv_len=kv_len, segments=segments)]
+
+    def count(index, grid, lo, hi):
+        steps = np.indices(grid).reshape(len(grid), -1)
+        blocks = np.stack(np.broadcast_arrays(
+            *(np.asarray(x) for x in index(*steps, lo, hi))))
+        return (int(np.all(blocks[:, 1:] == blocks[:, :-1], 0).sum()),
+                blocks.shape[1])
+
+    fwd = count(_kv_stream(H, G, nq), (B * H, nq, nk), plan[0], plan[1])
+    dkv = count(_q_stream(K, G, nk), (B * K, nk, G, nq), plan[2], plan[3])
+    return {"fwd": fwd, "dq": fwd, "dkv": dkv}
 
 
 def _resolve_spec(spec, S, T, causal, kv_len, segments):
@@ -229,13 +315,13 @@ def _tdot(a, b):
 # forward: online softmax over kv tiles, carries in VMEM scratch
 # --------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, kl_ref, qs_ref, ks_ref, o_ref, lse_ref,
-                m_acc, l_acc, acc, *, scale, spec, bq, bk,
-                n_k_tiles, s_len, t_len):
+def _fwd_kernel(lo_ref, hi_ref, q_ref, k_ref, v_ref, kl_ref, qs_ref, ks_ref,
+                o_ref, lse_ref, m_acc, l_acc, acc, *, scale, spec, bq, bk,
+                heads, n_q_tiles, n_k_tiles, s_len, t_len):
     i, j = pl.program_id(1), pl.program_id(2)
+    own = _clamp(j, lo_ref, hi_ref,
+                 (pl.program_id(0) // heads) * n_q_tiles + i) == j
     kl = kl_ref[0, 0]
-    qseg = qs_ref[0] if spec.has_segments else None
-    kseg = ks_ref[0] if spec.has_segments else None
 
     @pl.when(j == 0)
     def _init():
@@ -243,8 +329,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kl_ref, qs_ref, ks_ref, o_ref, lse_ref,
         l_acc[...] = jnp.zeros_like(l_acc)
         acc[...] = jnp.zeros_like(acc)
 
-    @pl.when(_run_pair(i, j, bq, bk, spec, kl, qseg, kseg, s_len, t_len))
-    def _compute():
+    @_when_live(own, i, j, bq, bk, spec, kl, qs_ref, ks_ref, s_len, t_len)
+    def _compute(qseg, kseg):
         s = _sdot(q_ref[0, 0], k_ref[0, 0]) * scale
         valid, _ = _masks(i, j, bq, bk, spec, kl, qseg, kseg, s_len, t_len)
         s = jnp.where(valid, s, _NEG)
@@ -292,36 +378,42 @@ def mha_fwd(q, k, v, kv_len=None, *, scale: float, causal: bool = True,
     grid = (B * H, pl.cdiv(S, bq), pl.cdiv(T, bk))
     kl = jnp.asarray(T if kv_len is None else kv_len,
                      jnp.int32).reshape(1, 1)
-    qs, ks, qs_spec, ks_spec = _seg_blocks(spec, segments, B, S, T, bq, bk, H)
+    plan = fetch_plan(spec, S, T, bq, bk, batch=B, kv_len=kv_len,
+                      segments=segments)
+    kv_map = _kv_stream(H, G, grid[1])
+    qmap = lambda bh, i, j, *_: (bh // H, bh % H, i, 0)
+    qs, ks, qs_spec, ks_spec = _seg_blocks(
+        spec, segments, B, S, T, bq, bk, lambda bh, i, j, *_: (bh // H, i, 0),
+        lambda *a: (a[0] // H, 0, kv_map(*a)[2]))
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, spec=spec,
-                          bq=bq, bk=bk, n_k_tiles=grid[2],
-                          s_len=S, t_len=T),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, hd), lambda bh, i, j: (bh // H, bh % H, i, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda bh, i, j: (bh // H, (bh % H) // G, j, 0)),
-            pl.BlockSpec((1, 1, bk, hdv), lambda bh, i, j: (bh // H, (bh % H) // G, j, 0)),
-            pl.BlockSpec((1, 1), lambda bh, i, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            qs_spec, ks_spec,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, hdv), lambda bh, i, j: (bh // H, bh % H, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda bh, i, j: (bh // H, bh % H, i, 0)),
-        ],
+                          bq=bq, bk=bk, heads=H, n_q_tiles=grid[1],
+                          n_k_tiles=grid[2], s_len=S, t_len=T),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, bq, hd), qmap),
+                pl.BlockSpec((1, 1, bk, hd), kv_map),
+                pl.BlockSpec((1, 1, bk, hdv), kv_map),
+                pl.BlockSpec((1, 1), lambda *_: (0, 0),
+                             memory_space=pltpu.SMEM),
+                qs_spec, ks_spec,
+            ],
+            out_specs=[pl.BlockSpec((1, 1, bq, hdv), qmap),
+                       pl.BlockSpec((1, 1, bq, 1), qmap)],
+            scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
+                            pltpu.VMEM((bq, 1), jnp.float32),
+                            pltpu.VMEM((bq, hdv), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((B, H, S, hdv), q.dtype),
                    jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, hdv), jnp.float32)],
         interpret=interpret,
         compiler_params=COMPILER_PARAMS,
         name="attn_fwd",
-    )(qt, kt, vt, kl, qs, ks)
+    )(plan.kv_lo.reshape(-1), plan.kv_hi.reshape(-1), qt, kt, vt, kl, qs, ks)
     return jnp.swapaxes(out, 1, 2), lse[..., 0]
 
 
@@ -329,20 +421,20 @@ def mha_fwd(q, k, v, kv_len=None, *, scale: float, causal: bool = True,
 # backward dQ: recompute score tiles, dQ accumulator resident per q tile
 # --------------------------------------------------------------------------
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, kl_ref, qs_ref,
-               ks_ref, dq_ref, acc, *, scale, spec, bq, bk, n_k_tiles,
-               s_len, t_len):
+def _dq_kernel(lo_ref, hi_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
+               kl_ref, qs_ref, ks_ref, dq_ref, acc, *, scale, spec, bq, bk,
+               heads, n_q_tiles, n_k_tiles, s_len, t_len):
     i, j = pl.program_id(1), pl.program_id(2)
+    own = _clamp(j, lo_ref, hi_ref,
+                 (pl.program_id(0) // heads) * n_q_tiles + i) == j
     kl = kl_ref[0, 0]
-    qseg = qs_ref[0] if spec.has_segments else None
-    kseg = ks_ref[0] if spec.has_segments else None
 
     @pl.when(j == 0)
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    @pl.when(_run_pair(i, j, bq, bk, spec, kl, qseg, kseg, s_len, t_len))
-    def _compute():
+    @_when_live(own, i, j, bq, bk, spec, kl, qs_ref, ks_ref, s_len, t_len)
+    def _compute(qseg, kseg):
         s = _sdot(q_ref[0, 0], k_ref[0, 0]) * scale
         valid, _ = _masks(i, j, bq, bk, spec, kl, qseg, kseg, s_len, t_len)
         p = jnp.where(valid, jnp.exp(s - lse_ref[0, 0]), 0.0)
@@ -378,31 +470,39 @@ def mha_bwd_dq(q, k, v, dout, lse, delta, kv_len=None, *, scale: float,
     grid = (B * H, pl.cdiv(S, bq), pl.cdiv(T, bk))
     kl = jnp.asarray(T if kv_len is None else kv_len,
                      jnp.int32).reshape(1, 1)
-    qs, ks, qs_spec, ks_spec = _seg_blocks(spec, segments, B, S, T, bq, bk, H)
-    row = pl.BlockSpec((1, 1, bq, 1), lambda bh, i, j: (bh // H, bh % H, i, 0))
+    plan = fetch_plan(spec, S, T, bq, bk, batch=B, kv_len=kv_len,
+                      segments=segments)
+    kv_map = _kv_stream(H, G, grid[1])
+    qmap = lambda bh, i, j, *_: (bh // H, bh % H, i, 0)
+    qs, ks, qs_spec, ks_spec = _seg_blocks(
+        spec, segments, B, S, T, bq, bk, lambda bh, i, j, *_: (bh // H, i, 0),
+        lambda *a: (a[0] // H, 0, kv_map(*a)[2]))
+    row = pl.BlockSpec((1, 1, bq, 1), qmap)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, spec=spec,
-                          bq=bq, bk=bk, n_k_tiles=grid[2],
-                          s_len=S, t_len=T),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, hd), lambda bh, i, j: (bh // H, bh % H, i, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda bh, i, j: (bh // H, (bh % H) // G, j, 0)),
-            pl.BlockSpec((1, 1, bk, hdv), lambda bh, i, j: (bh // H, (bh % H) // G, j, 0)),
-            pl.BlockSpec((1, 1, bq, hdv), lambda bh, i, j: (bh // H, bh % H, i, 0)),
-            row, row,
-            pl.BlockSpec((1, 1), lambda bh, i, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            qs_spec, ks_spec,
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, hd),
-                               lambda bh, i, j: (bh // H, bh % H, i, 0)),
+                          bq=bq, bk=bk, heads=H, n_q_tiles=grid[1],
+                          n_k_tiles=grid[2], s_len=S, t_len=T),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, bq, hd), qmap),
+                pl.BlockSpec((1, 1, bk, hd), kv_map),
+                pl.BlockSpec((1, 1, bk, hdv), kv_map),
+                pl.BlockSpec((1, 1, bq, hdv), qmap),
+                row, row,
+                pl.BlockSpec((1, 1), lambda *_: (0, 0),
+                             memory_space=pltpu.SMEM),
+                qs_spec, ks_spec,
+            ],
+            out_specs=pl.BlockSpec((1, 1, bq, hd), qmap),
+            scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
         interpret=interpret,
         compiler_params=COMPILER_PARAMS,
         name="attn_bwd_dq",
-    )(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
+    )(plan.kv_lo.reshape(-1), plan.kv_hi.reshape(-1),
+      jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
       jnp.swapaxes(dout, 1, 2), lse[..., None], delta[..., None], kl, qs, ks)
     return jnp.swapaxes(dq, 1, 2)
 
@@ -411,21 +511,22 @@ def mha_bwd_dq(q, k, v, dout, lse, delta, kv_len=None, *, scale: float,
 # backward dK/dV: kv tile resident while (group x q) tiles stream
 # --------------------------------------------------------------------------
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, kl_ref, qs_ref,
-                ks_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, spec,
-                bq, bk, n_g, n_q_tiles, s_len, t_len):
+def _dkv_kernel(lo_ref, hi_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
+                kl_ref, qs_ref, ks_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                scale, spec, bq, bk, kv_heads, n_g, n_q_tiles, n_k_tiles,
+                s_len, t_len):
     j, g, i = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    own = _clamp(i, lo_ref, hi_ref,
+                 (pl.program_id(0) // kv_heads) * n_k_tiles + j) == i
     kl = kl_ref[0, 0]
-    qseg = qs_ref[0] if spec.has_segments else None
-    kseg = ks_ref[0] if spec.has_segments else None
 
     @pl.when((g == 0) & (i == 0))
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(_run_pair(i, j, bq, bk, spec, kl, qseg, kseg, s_len, t_len))
-    def _compute():
+    @_when_live(own, i, j, bq, bk, spec, kl, qs_ref, ks_ref, s_len, t_len)
+    def _compute(qseg, kseg):
         # the q (token) axis is contracted here, so — unlike forward/dQ —
         # undefined remainder *rows* must be zeroed on both operand sides
         qrows = i * bq + jax.lax.broadcasted_iota(jnp.int32,
@@ -473,35 +574,43 @@ def mha_bwd_dkv(q, k, v, dout, lse, delta, kv_len=None, *, scale: float,
     grid = (B * K, pl.cdiv(T, bk), G, pl.cdiv(S, bq))
     kl = jnp.asarray(T if kv_len is None else kv_len,
                      jnp.int32).reshape(1, 1)
-    qs, ks, qs_spec, ks_spec = _seg_blocks(spec, segments, B, S, T, bq, bk,
-                                           K, dkv=True)
-    qmap = lambda bk_, j, g, i: (bk_ // K, (bk_ % K) * G + g, i, 0)
-    kvmap = lambda bk_, j, g, i: (bk_ // K, bk_ % K, j, 0)
+    plan = fetch_plan(spec, S, T, bq, bk, batch=B, kv_len=kv_len,
+                      segments=segments)
+    qmap = _q_stream(K, G, grid[1])
+    kvmap = lambda bk_, j, g, i, *_: (bk_ // K, bk_ % K, j, 0)
+    qs, ks, qs_spec, ks_spec = _seg_blocks(
+        spec, segments, B, S, T, bq, bk,
+        lambda *a: (a[0] // K, qmap(*a)[2], 0),
+        lambda bk_, j, g, i, *_: (bk_ // K, 0, j))
     row = pl.BlockSpec((1, 1, bq, 1), qmap)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, spec=spec,
-                          bq=bq, bk=bk, n_g=G,
-                          n_q_tiles=grid[3], s_len=S, t_len=T),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, hd), qmap),
-            pl.BlockSpec((1, 1, bk, hd), kvmap),
-            pl.BlockSpec((1, 1, bk, hdv), kvmap),
-            pl.BlockSpec((1, 1, bq, hdv), qmap),
-            row, row,
-            pl.BlockSpec((1, 1), lambda bk_, j, g, i: (0, 0),
-                         memory_space=pltpu.SMEM),
-            qs_spec, ks_spec,
-        ],
-        out_specs=[pl.BlockSpec((1, 1, bk, hd), kvmap),
-                   pl.BlockSpec((1, 1, bk, hdv), kvmap)],
+                          bq=bq, bk=bk, kv_heads=K, n_g=G,
+                          n_q_tiles=grid[3], n_k_tiles=grid[1], s_len=S,
+                          t_len=T),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, bq, hd), qmap),
+                pl.BlockSpec((1, 1, bk, hd), kvmap),
+                pl.BlockSpec((1, 1, bk, hdv), kvmap),
+                pl.BlockSpec((1, 1, bq, hdv), qmap),
+                row, row,
+                pl.BlockSpec((1, 1), lambda *_: (0, 0),
+                             memory_space=pltpu.SMEM),
+                qs_spec, ks_spec,
+            ],
+            out_specs=[pl.BlockSpec((1, 1, bk, hd), kvmap),
+                       pl.BlockSpec((1, 1, bk, hdv), kvmap)],
+            scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
+                            pltpu.VMEM((bk, hdv), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((B, K, T, hd), k.dtype),
                    jax.ShapeDtypeStruct((B, K, T, hdv), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
-                        pltpu.VMEM((bk, hdv), jnp.float32)],
         interpret=interpret,
         compiler_params=COMPILER_PARAMS,
         name="attn_bwd_dkv",
-    )(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
+    )(plan.q_lo.reshape(-1), plan.q_hi.reshape(-1),
+      jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
       jnp.swapaxes(dout, 1, 2), lse[..., None], delta[..., None], kl, qs, ks)
     return jnp.swapaxes(dk, 1, 2), jnp.swapaxes(dv, 1, 2)

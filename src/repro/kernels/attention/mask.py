@@ -37,6 +37,8 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 
+_I32 = jnp.iinfo(jnp.int32)
+
 
 class MaskSpec(NamedTuple):
     """Static (hashable) description of an attention mask.
@@ -92,3 +94,62 @@ def mask_array(spec: MaskSpec, S: int, T: int, *, kv_len=None,
         q_seg, kv_seg = segments
         valid = valid & (q_seg[:, :, None] == kv_seg[:, None, :])
     return valid
+
+
+class FetchPlan(NamedTuple):
+    """Live tile ranges of one attention call, (B, n_tiles) int32 each.
+
+    ``kv_lo[b, i]``/``kv_hi[b, i]`` are the first and last kv tile that q
+    tile ``i`` of row ``b`` shares a live pair with; ``q_lo[b, j]``/
+    ``q_hi[b, j]`` the first and last q tile of kv tile ``j``. A tile with
+    no live partner gets (0, 0).
+    """
+    kv_lo: jnp.ndarray
+    kv_hi: jnp.ndarray
+    q_lo: jnp.ndarray
+    q_hi: jnp.ndarray
+
+
+def _tile_ranges(seg, n: int, b: int, length: int):
+    """Per-tile (min, max) of (B, length) ids over the in-bounds lanes."""
+    s = jnp.pad(jnp.asarray(seg, jnp.int32), ((0, 0), (0, n * b - length)))
+    s = s.reshape(s.shape[0], n, b)
+    inb = (jnp.arange(n * b) < length).reshape(n, b)
+    return (jnp.min(jnp.where(inb, s, _I32.max), -1),
+            jnp.max(jnp.where(inb, s, _I32.min), -1))
+
+
+def _first_last(live, axis: int):
+    n = live.shape[axis]
+    some = live.any(axis)
+    first = jnp.argmax(live, axis)
+    last = n - 1 - jnp.argmax(jnp.flip(live, axis), axis)
+    return (jnp.where(some, first, 0).astype(jnp.int32),
+            jnp.where(some, last, 0).astype(jnp.int32))
+
+
+def fetch_plan(spec: MaskSpec, S: int, T: int, bq: int, bk: int, *,
+               batch: int, kv_len=None, segments=None) -> FetchPlan:
+    """The kernels' tile-skip test over every (q tile, kv tile) pair.
+
+    The same test as the kernels' per-step predicate: the causal diagonal
+    with its offset, the ``kv_len`` fill bound, and — with segments — the
+    overlap of the two tiles' id ranges, lanes past S/T left out. Packed
+    rows hold sorted ids, so the live kv tiles of a q tile (and the live q
+    tiles of a kv tile) form one run; a pad tail (id 0) only widens a
+    range. Works on traced and on numpy operands.
+    """
+    nq, nk = -(-S // bq), -(-T // bk)
+    i = jnp.arange(nq)[:, None]
+    j = jnp.arange(nk)[None, :]
+    live = j * bk < (T if kv_len is None else kv_len)
+    if spec.causal:
+        live &= spec.offset + (i + 1) * bq - 1 >= j * bk
+    live = live[None]
+    if spec.has_segments:
+        q_lo, q_hi = _tile_ranges(segments[0], nq, bq, S)
+        k_lo, k_hi = _tile_ranges(segments[1], nk, bk, T)
+        live &= ((q_lo[:, :, None] <= k_hi[:, None, :])
+                 & (k_lo[:, None, :] <= q_hi[:, :, None]))
+    live = jnp.broadcast_to(live, (batch, nq, nk))
+    return FetchPlan(*_first_last(live, 2), *_first_last(live, 1))

@@ -6,8 +6,9 @@ Layered like the xent tests: first pin the scan reference (including the
 rectangular-causal T > S support cached prefill continuation needs), then
 hold the fused dispatch path — interpret oracle on CPU — to it for the
 forward and dQ/dK/dV across dtypes, GQA ratios, ragged shapes, the
-``kv_len`` decode bound and fully-masked rows, and finally the sharded
-matrix in a subprocess mesh.
+``kv_len`` decode bound, fully-masked rows and packed rows over many tiles,
+check the kernels' fetch plan against their tile test, and finally the
+sharded matrix in a subprocess mesh.
 """
 import os
 import subprocess
@@ -21,7 +22,9 @@ import pytest
 
 from conftest import repro_fused
 from repro.kernels import dispatch
+from repro.kernels.attention import attention as ak
 from repro.kernels.attention import ref as aref
+from repro.kernels.attention.mask import fetch_plan, mask_spec
 from repro.models.layers import (_pick_block, causal_blockwise_attention,
                                  chunked_q_attention, decode_attention,
                                  flash_attention, largest_divisor)
@@ -169,19 +172,26 @@ def test_fused_flash_matches_reference(shape, dtype):
     each of the G group heads' dK/dV before summing them, an error of its
     own that the kernels (one f32 accumulation) do not make."""
     B, S, T, H, K, hd, causal = shape
-    q, k, v, dout = _gqa(B, S, T, H, K, hd, dtype, seed=3)
+    _check_fused_parity(*_gqa(B, S, T, H, K, hd, dtype, seed=3), causal)
+
+
+def _check_fused_parity(q, k, v, dout, causal, segments=None, block=None,
+                        kv_len=None):
+    H, K, hd, dtype = q.shape[2], k.shape[2], q.shape[3], q.dtype
     scale = hd ** -0.5
     assert dispatch.attn_route(q.shape, k.shape, causal)[0] == "kernel"
     f32 = lambda x: x.astype(jnp.float32)
 
     def f_fused(q, k, v):
         return jnp.sum(f32(dispatch.flash_attention(
-            q, k, v, scale=scale, causal=causal)) * f32(dout))
+            q, k, v, scale=scale, causal=causal, segments=segments,
+            kv_len=kv_len, block=block)) * f32(dout))
 
     # grad through the repeat sums group heads back onto (B, T, K, hd)
     def terms_ref(q, k, v):
         kf, vf = jnp.repeat(k, H // K, 2), jnp.repeat(v, H // K, 2)
-        return naive(q, kf, vf, scale, causal) * f32(dout)
+        return aref.attention(q, kf, vf, scale=scale, causal=causal,
+                              segments=segments, kv_len=kv_len) * f32(dout)
 
     v1, g1 = jax.value_and_grad(f_fused, argnums=(0, 1, 2))(q, k, v)
     with jax.default_matmul_precision("highest"):
@@ -199,6 +209,151 @@ def test_fused_flash_matches_reference(shape, dtype):
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32), atol=tol,
                                    err_msg=name)
+
+
+def _packed_ids(S, *rows):
+    """(len(rows), S) int32 segment ids: each row's documents of the given
+    lengths from lane 0 on, ids 1, 2, ...; the rest of the row is pad (0)."""
+    seg = np.zeros((len(rows), S), np.int32)
+    for b, lens in enumerate(rows):
+        ends = np.cumsum(lens)
+        for d, (a, e) in enumerate(zip(ends - lens, ends), 1):
+            seg[b, a:e] = d
+    return jnp.asarray(seg)
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("lens", [
+    # 200 lanes in 32-lane tiles: the last document ends on a tile edge
+    # (lane 160), so the last tiles (160-191, 192-199) hold pad only
+    ([30, 66, 64], [10, 118, 32]),
+    # one document over five of seven tiles between two short ones
+    ([5, 150, 45],),
+], ids=["pad_tiles", "long_doc"])
+def test_fused_packed_matches_reference(lens, dtype):
+    """Packed rows over many tiles, where most tile pairs are dead and
+    their steps fetch no block: the kernels still match the oracle with
+    its segment mask, forward and all three gradients."""
+    seg = _packed_ids(200, *lens)
+    q, k, v, dout = _gqa(seg.shape[0], 200, 200, 2, 1, 16, dtype, seed=15)
+    _check_fused_parity(q, k, v, dout, True, (seg, seg), block=(32, 32))
+
+
+@pytest.mark.parametrize("S,T,causal,kv_len", [
+    (160, 160, True, None),     # unpacked causal: dead above the diagonal
+    (96, 224, True, None),      # causal offset T > S
+    (8, 224, False, 77),        # decode: dead past the cache fill
+    (96, 160, False, None),     # cross: every pair live
+], ids=["causal", "offset", "decode", "cross"])
+def test_fused_tiled_matches_reference(S, T, causal, kv_len):
+    """Unpacked calls over many 32-lane tiles, each with the fetch plan its
+    own mask gives, against the oracle: forward and all three gradients."""
+    q, k, v, dout = _gqa(1, S, T, 2, 1, 16, seed=16)
+    _check_fused_parity(q, k, v, dout, causal, block=(32, 32),
+                        kv_len=None if kv_len is None else jnp.asarray(kv_len))
+
+
+def _live_tiles(S, T, bq, bk, causal, kv_len=None, segments=None):
+    """(B, n_q, n_k) bool: the kernels' per-step tile test, pair by pair
+    over numpy ids (ranges over the lanes inside S and T)."""
+    nq, nk = -(-S // bq), -(-T // bk)
+    offset = T - S if causal else 0
+    kl = T if kv_len is None else kv_len
+    segs = (np.zeros((1, S), int), np.zeros((1, T), int)) \
+        if segments is None else [np.asarray(x) for x in segments]
+    live = np.zeros((segs[0].shape[0], nq, nk), bool)
+    for b, i, j in np.ndindex(live.shape):
+        qs = segs[0][b, i * bq:min((i + 1) * bq, S)]
+        ks = segs[1][b, j * bk:min((j + 1) * bk, T)]
+        live[b, i, j] = (j * bk < kl
+                         and (not causal
+                              or offset + (i + 1) * bq - 1 >= j * bk)
+                         and qs.min() <= ks.max() and ks.min() <= qs.max())
+    return live
+
+
+def _random_rows(rng, B, S, tile, pad):
+    """Packed rows of random documents; some end on a tile edge, and with
+    ``pad`` a row may end in a pad tail."""
+    rows = []
+    for _ in range(B):
+        lens, left = [], S - (int(rng.integers(0, S // 2)) if pad else 0)
+        while left > 0:
+            n = int(rng.choice([rng.integers(1, 3 * tile), tile,
+                                2 * tile]))
+            lens.append(min(n, left))
+            left -= lens[-1]
+        rows.append(lens)
+    return _packed_ids(S, *rows)
+
+
+@pytest.mark.parametrize("S,T,bq,bk,causal,kv_len,packed", [
+    (256, 256, 32, 32, True, None, "pad"),      # pad tails, tile edges
+    (200, 200, 32, 64, True, None, "pad"),      # remainder tiles
+    (200, 200, 32, 32, True, None, "full"),     # rows without pad
+    (96, 224, 32, 64, True, None, "full"),      # causal offset T > S
+    (256, 256, 64, 32, True, None, None),       # unpacked causal
+    (8, 200, 8, 32, False, 77, None),           # kv_len decode
+    (64, 200, 32, 32, False, None, None),       # cross, no segments
+    (128, 128, 32, 32, False, None, "pad"),     # non-causal packed
+], ids=["pad", "remainder", "no_pad", "offset", "causal", "decode",
+        "cross", "noncausal_packed"])
+def test_fetch_plan_covers_every_live_tile(S, T, bq, bk, causal, kv_len,
+                                           packed):
+    """Every tile pair the kernels compute lies inside the fetch plan's
+    range, in both orientations, and each range starts and ends on a live
+    pair (a tile with none gets (0, 0))."""
+    rng = np.random.default_rng(S + T + bq)
+    B = 3
+    segments = None
+    if packed:
+        seg = _random_rows(rng, B, S, bq, packed == "pad")
+        segments = (seg, seg if S == T else
+                    _random_rows(rng, B, T, bk, packed == "pad"))
+        if S != T:  # the queries are the last S keys of each row
+            segments = (segments[1][:, T - S:], segments[1])
+    spec = mask_spec(S, T, causal=causal, segments=segments,
+                     kv_len=None if kv_len is None else jnp.asarray(kv_len))
+    plan = [np.asarray(x) for x in fetch_plan(
+        spec, S, T, bq, bk, batch=B, segments=segments,
+        kv_len=None if kv_len is None else jnp.asarray(kv_len))]
+    live = np.broadcast_to(_live_tiles(S, T, bq, bk, causal, kv_len,
+                                       segments), (B, -(-S // bq),
+                                                   -(-T // bk)))
+    assert live.any()
+    for lo, hi, lv in ((plan[0], plan[1], live),
+                       (plan[2], plan[3], live.transpose(0, 2, 1))):
+        t = np.arange(lv.shape[2])
+        inside = (t >= lo[..., None]) & (t <= hi[..., None])
+        assert not (lv & ~inside).any()
+        some = lv.any(2)
+        pick = lambda x: np.take_along_axis(lv, x[..., None], 2)[..., 0]
+        assert (pick(lo) | ~some).all() and (pick(hi) | ~some).all()
+        assert (lo[~some] == 0).all() and (hi[~some] == 0).all()
+
+
+def test_fetch_repeats_hand_count():
+    """Skipped copies of a fixed layout, counted by hand: one 1024-lane
+    row, 512-lane tiles, 4 query heads on 2 kv heads, two documents that
+    meet on the tile edge (the off-diagonal tile pairs are dead) against
+    one document over the whole row (only the pair above the diagonal
+    is)."""
+    q = jax.ShapeDtypeStruct((1, 1024, 4, 16), jnp.float32)
+    kv = jax.ShapeDtypeStruct((1, 1024, 2, 16), jnp.float32)
+    two = _packed_ids(1024, [512, 512])
+    # forward/dQ: each head reads kv tiles 0, 0 for q tile 0 and 1, 1 for
+    # q tile 1 -> 2 repeats a head; dK/dV likewise for each (kv, group)
+    assert ak.fetch_repeats(q, kv, kv, segments=(two, two)) == {
+        "fwd": (8, 16), "dq": (8, 16), "dkv": (8, 16)}
+    one = _packed_ids(1024, [1024])
+    # forward/dQ: kv tiles 0, 0 | 0, 1 per head -> 2 repeats a head;
+    # dK/dV: q tiles 0, 1 under kv tile 0 (no repeat), 1, 1 under kv
+    # tile 1 for each of the 2 group heads -> 2 repeats a kv head
+    assert ak.fetch_repeats(q, kv, kv, segments=(one, one)) == {
+        "fwd": (8, 16), "dq": (8, 16), "dkv": (4, 16)}
+    # the same unpacked causal call: the plan comes from the diagonal alone
+    assert ak.fetch_repeats(q, kv, kv) == ak.fetch_repeats(
+        q, kv, kv, segments=(one, one))
 
 
 def test_fused_decode_over_cache_kv_len():

@@ -6,7 +6,8 @@ a described (not attached) ``v5e:2x2`` topology, so these tests hand each
 kernel ``ShapeDtypeStruct`` operands placed on one described chip and
 compile it. Shapes are those of the paper's LLaMA-1B/7B training steps:
 the xent head at N=2048 tokens and V=32000, attention at head_dim 64
-(T=256) and 128 (T=4096), the SCALE update on the stacked MLP weight of
+(T=256) and 128 (T=4096) and packed 16k rows at Qwen2-7B's heads, the
+SCALE update on the stacked MLP weight of
 LLaMA-1B and the LM-head momentum.
 
 The topology is described inside a module fixture, never at import: only
@@ -87,6 +88,27 @@ def test_attention_compiles(one_chip, kernel, B, S, H, K, hd):
     row = ((B, H, S), F32)
     _compile(lambda q, k, v, do, lse, dl: bwd(q, k, v, do, lse, dl, **kw),
              one_chip, q, kv, kv, q, row, row)
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+def test_packed_attention_compiles(one_chip, kernel):
+    """Packed rows at the benchmark's 16k shape (Qwen2-7B's heads): the
+    segment operands, the scalar-prefetched fetch plan and the clamped
+    index maps compile for the chip."""
+    B, S, H, K, hd = 1, 16384, 28, 4, 128
+    q, kv = ((B, S, H, hd), BF16), ((B, S, K, hd), BF16)
+    seg = ((B, S), I32)
+    kw = dict(scale=hd ** -0.5, causal=True, interpret=False)
+    if kernel == "fwd":
+        _compile(lambda q, k, v, s: ak.mha_fwd(q, k, v, segments=(s, s),
+                                                **kw),
+                 one_chip, q, kv, kv, seg)
+        return
+    bwd = ak.mha_bwd_dq if kernel == "dq" else ak.mha_bwd_dkv
+    row = ((B, H, S), F32)
+    _compile(lambda q, k, v, do, lse, dl, s: bwd(q, k, v, do, lse, dl,
+                                                 segments=(s, s), **kw),
+             one_chip, q, kv, kv, q, row, row, seg)
 
 
 MLP = (24, 2048, 5461)   # LLaMA-1B's stacked (layers, d_model, d_ff) weight
