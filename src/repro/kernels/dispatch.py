@@ -244,6 +244,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
+from jax.experimental.xla_metadata import set_xla_metadata
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .attention import attention as _ak
@@ -382,7 +383,15 @@ def _red_axes(plan: ShardPlan, axis: str):
 
 def _psum_ss(ss, plan, axis):
     axes = _red_axes(plan, axis)
-    return jax.lax.psum(ss, axes) if axes else ss
+    if not axes:
+        return ss
+    # the one collective SCALE adds; the benchmark's norm_psum_share reads
+    # its device time by this name. XLA's all-reduce combiner would merge
+    # it with any independent all-reduce (the loss sum), and a merged op
+    # keeps the first one's name; the attribute keeps the column sums of
+    # one update in an all-reduce of their own.
+    with jax.named_scope("norm_psum"), set_xla_metadata(norm_psum="1"):
+        return jax.lax.psum(ss, axes)
 
 
 def _mapped(body, plan, n_arrays, n_outs=1):

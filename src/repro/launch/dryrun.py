@@ -25,9 +25,9 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_cell, iter_cells
-from repro.core import make_optimizer
 from repro.launch import hlo_analysis as H
 from repro.launch.mesh import HW, make_production_mesh
+from repro.launch.train import make_tx
 from repro.models import (cache_logical_axes, count_params, init_cache,
                           init_params, param_logical_axes, param_shapes)
 from repro.models.sharding import Rules, tree_shardings
@@ -118,7 +118,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
             n_accum = int(accum)
         n_total = count_params(param_shapes(cfg))
         accum_dtype = "bfloat16" if n_total > 150e9 else "float32"
-        tx = make_optimizer(optimizer, 1e-3)
+        tx = make_tx(cfg, optimizer, 1e-3)
         step = make_train_step(cfg, tx, grad_accum=n_accum, rules=rules,
                                accum_dtype=accum_dtype, norm_metrics=False)
         opt_abs = jax.eval_shape(lambda: tx.init(params_abs))

@@ -101,6 +101,11 @@ def build(args, lr_scale: float = 1.0):
     if getattr(args, "tie_embeddings", False):
         cfg.tie_embeddings = True
     sched = linear_warmup_cosine(args.lr * lr_scale, args.steps)
+    return cfg, make_tx(cfg, args.optimizer, sched)
+
+
+def make_tx(cfg, optimizer: str, lr):
+    """``make_optimizer(optimizer, lr)`` for ``cfg``'s parameter tree."""
     if cfg.tie_embeddings:
         # feature-detect rather than enumerate names (like the trainer's
         # shardings/grad_scale detection): any optimizer whose factory
@@ -113,12 +118,10 @@ def build(args, lr_scale: float = 1.0):
         # axis as defined.
         from repro.core import OPTIMIZER_REGISTRY
         from repro.core.labels import LabelRules
-        spec = OPTIMIZER_REGISTRY.get(args.optimizer.lower())
+        spec = OPTIMIZER_REGISTRY.get(optimizer.lower())
         if spec is not None and "rules" in spec.valid_kwargs():
-            return cfg, make_optimizer(args.optimizer, sched,
-                                       rules=LabelRules.tied())
-    tx = make_optimizer(args.optimizer, sched)
-    return cfg, tx
+            return make_optimizer(optimizer, lr, rules=LabelRules.tied())
+    return make_optimizer(optimizer, lr)
 
 
 def main(argv=None):

@@ -11,7 +11,7 @@ architecture lowers through ``jax.lax.scan`` (compile-time O(1) in depth):
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -70,6 +70,16 @@ class ModelConfig:
 
     rope_theta: float = 10000.0
     rms_eps: float = 1e-5
+    # Scalar multipliers (Granite's equations); each default is neutral and
+    # leaves the traced program as it is. The embedding output is scaled
+    # by embedding_multiplier, each sub-layer's output by
+    # residual_multiplier before its residual add, attention scores by
+    # attention_multiplier (None: 1/sqrt(head_dim)), and the logits are
+    # divided by logits_scaling (folded into the head's input).
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
     # Weight tying: the param tree has no lm_head; tok_embed.w ((V, D), or
     # (C, V, D) audio) doubles as the head read transposed. Optimizers that
     # special-case the head must use LabelRules.tied() (see models.model).

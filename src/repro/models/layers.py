@@ -549,7 +549,8 @@ def apply_attention(p, cfg: ModelConfig, x, positions, rules: Rules,
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
 
-    scale = 1.0 / math.sqrt(hd)
+    scale = (1.0 / math.sqrt(hd) if cfg.attention_multiplier is None
+             else cfg.attention_multiplier)
     new_cache = cache
     if mode == "decode" and kv_source is None:
         # insert this step's k/v at cache_index, attend over the cache.

@@ -151,6 +151,8 @@ def forward(params, cfg: ModelConfig, tokens, *, image_embeds=None,
     positions restart at every document boundary) and the per-token
     document ids the attention mask keeps separated (pad id 0). When
     ``positions`` is None the usual 0..S-1 (or cache-offset) ramp is used.
+    The returned hidden state is the head's input: final-normed and, where
+    ``cfg.logits_scaling`` is set, divided by it.
     """
     rules = rules or Rules(cfg.rule_overrides)
     S = tokens.shape[-1]
@@ -171,6 +173,8 @@ def forward(params, cfg: ModelConfig, tokens, *, image_embeds=None,
                     for c in range(cfg.n_codebooks))
         else:
             x = jnp.take(ew, tokens, axis=0)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
         x = shard(x, ("act_batch", "act_seq", "act_embed"), rules)
         if cfg.pos_embed == "learned":
             x = x + jnp.take(params["pos_embed"]["w"], positions, axis=0)
@@ -190,6 +194,11 @@ def forward(params, cfg: ModelConfig, tokens, *, image_embeds=None,
         aux = aux + seg_aux
     with jax.named_scope("final_norm"):
         x = L.rmsnorm(x, params["final_norm"]["s"], cfg.rms_eps)
+        if cfg.logits_scaling != 1.0:
+            # logits / s == (x / s) @ w: the scale rides on the head's
+            # input, so every head path (fused kernels, chunked scan,
+            # serving logits) sees it; exact for a power of two
+            x = x * jnp.asarray(1.0 / cfg.logits_scaling, x.dtype)
     return x, new_cache, aux
 
 

@@ -85,6 +85,8 @@ def _apply_sub(sub: str, p: dict, cfg: ModelConfig, x, positions, rules: Rules,
             y, cache = L.apply_mamba(p, cfg, h, rules, mode, cache)
         else:
             raise ValueError(sub)
+        if cfg.residual_multiplier != 1.0:
+            y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
         return x + y.astype(x.dtype), cache, aux
 
 

@@ -8,12 +8,13 @@ import pytest
 from repro.configs import ARCH_IDS, get_arch
 from repro.core import make_optimizer
 from repro.data import make_dataset
+from repro.launch.train import make_tx
 from repro.models import (count_params, forward, init_params,
                           logits_from_hidden, param_shapes)
 from repro.training import init_state, make_train_step
 
 NAMEPLATE_B = {
-    "deepseek-67b": (60, 75), "qwen2-7b": (7, 8.5), "granite-3-8b": (7.5, 9),
+    "deepseek-67b": (60, 75), "qwen2-7b": (7, 8.5), "granite-3-8b": (8.1, 8.3),
     "mistral-large-123b": (115, 130), "mamba2-370m": (0.3, 0.5),
     "llama-3.2-vision-11b": (9, 12), "dbrx-132b": (125, 140),
     "deepseek-v3-671b": (660, 685), "jamba-1.5-large-398b": (390, 405),
@@ -52,7 +53,7 @@ def test_smoke_forward_and_train_step(arch):
     if cfg.padded_vocab != cfg.vocab_size:
         assert float(jnp.max(logits[..., cfg.vocab_size:])) <= -1e8
 
-    tx = make_optimizer("scale", 1e-3)
+    tx = make_tx(cfg, "scale", 1e-3)
     step = jax.jit(make_train_step(cfg, tx))
     state = init_state(params, tx)
     state, metrics = step(state, batch)
