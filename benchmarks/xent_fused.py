@@ -10,8 +10,10 @@ write+read — best case for XLA); the backward recomputes the logits,
 materializes dlogits (write+read), writes the dH chunk, and reads+writes
 the f32 (D, V) dW accumulator the scan carries across every chunk. The
 fused path (:mod:`repro.kernels.xent`): forward reads h once and w once
-per token tile; dH the same plus one dH write; dW reads w once and h once
-per vocab tile plus one dW write — logits and dlogits never leave VMEM.
+per token tile; the backward reads w once, h once per vocab tile, writes
+the f32 dH partial sum per vocab tile (reading it back from the second),
+writes dW once, and dH is then rounded to the element size — logits and
+dlogits never leave VMEM.
 
 The memory figure of merit is the peak logit activation: the jnp path
 holds a (B*chunk, V) f32 block in HBM — O(S*V) as chunk approaches S —
@@ -47,8 +49,8 @@ CHUNK = 2048  # cfg.loss_chunk default
 def _tiles(N, D, V, be):
     from repro.kernels.xent.xent import _pick_blocks
     fwd = _pick_blocks(N, D, V, el_bytes=be)
-    dh = _pick_blocks(N, D, V, el_bytes=be, out_bytes=be, kernel="dh")
-    return fwd, dh
+    bwd = _pick_blocks(N, D, V, el_bytes=be, out_bytes=be, kernel="bwd")
+    return fwd, bwd
 
 
 def jnp_chunk_bytes(B, S, D, V, chunk, be=2):
@@ -68,12 +70,13 @@ def jnp_chunk_bytes(B, S, D, V, chunk, be=2):
 def fused_bytes(B, S, D, V, be=2):
     """(total_bytes, peak_logit_bytes) for the fused kernel path."""
     N = B * S
-    (bn_f, bv_f), (bn_h, _) = _tiles(N, D, V, be)
+    (bn_f, bv_f), (bn_b, bv_b) = _tiles(N, D, V, be)
     fwd = N * D * be + math.ceil(N / bn_f) * D * V * be
-    dh = 2 * N * D * be + math.ceil(N / bn_h) * D * V * be
-    dw = math.ceil(V / bv_f) * N * D * be + 2 * D * V * be
+    nv = math.ceil(V / bv_b)
+    bwd = (2 * D * V * be + nv * N * D * be + (2 * nv - 1) * N * D * 4
+           + N * D * (4 + be))
     # loss/lse/labels vectors are noise (N * 4 each)
-    return fwd + dh + dw, max(bn_f * bv_f, bn_h * bv_f) * 4
+    return fwd + bwd, max(bn_f * bv_f, bn_b * bv_b) * 4
 
 
 def _accounting_rows(heads, chunk):

@@ -858,16 +858,18 @@ def _xent_fused(vocab_size: int, interp: bool, plan, block,
                       col_offset=_voffset(_v_local(wb)),
                       transposed=transposed)
             # partial sums psum in f32, then round to the cotangent dtype
-            dh = _xk.xent_bwd_dh(
+            dh, dw = _xk.xent_bwd(
                 *args, **kw,
-                out_dtype=jnp.float32 if voc_axes else hb.dtype)
-            dw = _xk.xent_bwd_dw(
-                *args, **kw,
-                out_dtype=jnp.float32 if tok_axes else wb.dtype)
+                dw_dtype=jnp.float32 if tok_axes else wb.dtype)
             if voc_axes:
-                dh = jax.lax.psum(dh, voc_axes).astype(hb.dtype)
+                dh = jax.lax.psum(dh, voc_axes)
+            dh = dh.astype(hb.dtype)
             if tok_axes:
                 dw = jax.lax.psum(dw, tok_axes).astype(wb.dtype)
+                # the layers' backward needs dH at once: tied to it, the
+                # psum runs before that backward, and the kernel's f32 dW
+                # does not stay live through it (the step's memory peak)
+                dh, dw = jax.lax.optimization_barrier((dh, dw))
             return dh.reshape(hb.shape), dw
 
         if plan is None:

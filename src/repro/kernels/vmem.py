@@ -10,7 +10,9 @@ scoped limit at real widths, which only a compile for the chip shows.
 Each kernel asks for ``VMEM_LIMIT_BYTES`` of scoped VMEM (v5e/v6e hold
 128 MiB, the compiler's default scope is 16 MiB on v5e) and its picker fills
 at most ``VMEM_BUDGET_BYTES`` of it, counted with :func:`tile_bytes`; the
-rest is headroom for Mosaic's own scratch and for estimate error.
+rest is headroom for Mosaic's own scratch and for estimate error. The
+fused xent backward alone asks for ``WIDE_VMEM_LIMIT_BYTES`` and fills at
+most ``WIDE_VMEM_BUDGET_BYTES``.
 """
 from __future__ import annotations
 
@@ -20,6 +22,12 @@ VMEM_LIMIT_BYTES = 32 << 20
 VMEM_BUDGET_BYTES = 24 << 20
 # the scoped-VMEM request every tile-picked kernel compiles with
 COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+# the fused xent backward holds a resident f32 dW tile, its dW output
+# block and the w block at once; it asks for most of the chip's VMEM
+WIDE_VMEM_LIMIT_BYTES = 96 << 20
+WIDE_VMEM_BUDGET_BYTES = 80 << 20
+WIDE_COMPILER_PARAMS = pltpu.CompilerParams(
+    vmem_limit_bytes=WIDE_VMEM_LIMIT_BYTES)
 
 
 def tile_bytes(rows: int, cols: int, itemsize: int) -> int:

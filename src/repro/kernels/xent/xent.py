@@ -15,14 +15,17 @@ re-materializes it and streams a (D, V) f32 dW accumulator through HBM on
     ``ll`` (each (N, 1) f32 — noise next to the matrices) are emitted once
     at the last vocab tile. Peak logit storage is one (bn, bv) VMEM tile,
     independent of V and S.
-  * ``xent_bwd_dh`` — same tiling; recomputes the logit tile, forms
-    ``dlogits = (softmax - onehot(label)) * g`` in registers and
-    accumulates ``dlogits @ w_tile^T`` into a (bn, D) VMEM scratch, emitted
-    once per token tile. dlogits never exists beyond a (bn, bv) tile.
-  * ``xent_bwd_dw`` — transposed grid (vocab tiles outer, token tiles
-    inner): the (D, bv) dW tile stays resident in scratch while all token
-    tiles stream by, accumulating ``h_tile^T @ dlogits``; one dW write per
-    vocab tile (vs the scan's read+write of the full f32 dW per chunk).
+  * ``xent_bwd`` — transposed grid (vocab tiles outer, token tiles
+    inner). Each step forms one logit tile and ``dlogits = (softmax -
+    onehot(label)) * g`` once, in VMEM, and feeds both gradients from it:
+    ``h_tile^T @ dlogits`` into the (D, bv) dW tile, resident in scratch
+    while all token tiles stream by and written once per vocab tile, and
+    ``dlogits @ w_tile^T`` into the token tile's (bn, D) f32 dH partial
+    sum, which lives in HBM between vocab tiles (read and written by the
+    kernel's own DMAs, overlapped with the contractions). Forward and
+    backward form each logit twice in all: 8·D·V FLOPs a token against the
+    6·D·V that the loss and both gradients need (a backward kernel per
+    gradient would form it three times, 10·D·V).
 
 Masking folds three boundaries into the tile iota, mirroring the colnorm
 kernels' remainder handling (out-of-bounds block regions are undefined —
@@ -30,17 +33,22 @@ NaN in interpret mode — and 0*NaN = NaN, so *both* operands of every
 contraction are zeroed on padded positions):
 
   * padded vocab: global column id ``col_offset + j*bv + iota`` >=
-    ``vocab_size`` contributes neither to the logsumexp nor to dW, and w is
-    zeroed there before the dH contraction;
+    ``vocab_size`` contributes neither to the logsumexp nor to either
+    gradient (its dlogits are exactly 0);
   * remainder vocab tiles (local V % bv): lanes past the local w width are
     undefined memory whose *global* ids can still be < ``vocab_size`` on a
     non-last vocab shard, so validity is the conjunction of the local
     bound and the global one (see ``_col_masks``) — and the label one-hot
     uses the same mask so a label owned by another shard cannot match an
     undefined local lane carrying its global id;
-  * remainder token tiles (N % bn): forward/dH rows are independent and
-    clipped on write; dW zeroes h rows and dlogits rows past N before the
-    token contraction.
+  * remainder token tiles (N % bn): forward rows are independent and
+    clipped on write, backward dH rows land in padding the wrapper drops;
+    the backward zeroes h rows and dlogits rows past N before the token
+    contraction.
+
+The backward zeroes undefined operand lanes in the operand's own dtype and
+only in the variant compiled for a remainder tile; full tiles run unmasked
+operands.
 
 ``col_offset`` is a traced SMEM scalar: under a vocab-sharded mesh the
 dispatch layer passes ``shard_index * local_V`` so labels (global ids)
@@ -56,7 +64,7 @@ Transposed-w variants (``transposed=True`` on every entry point): the head
 is a **tied embedding** stored (V, D) instead of the use layout (D, V).
 Blocks then index ``w[vocab_tile, d]`` — the logit tile is the same
 (bn, bv) MXU contraction with w's dims swapped, the column masks apply to
-w's *rows*, and ``xent_bwd_dw`` emits dW in (V, D) layout so the gradient
+w's *rows*, and ``xent_bwd`` emits dW in (V, D) layout so the gradient
 lands directly on the embedding without a transpose pass. Tile sizes,
 masking and the online-logsumexp recurrence are identical (one code path,
 the ``wt`` static flag only swaps the w-side indexing).
@@ -70,7 +78,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..vmem import COMPILER_PARAMS, VMEM_BUDGET_BYTES, tile_bytes
+from ..vmem import (COMPILER_PARAMS, VMEM_BUDGET_BYTES,
+                    WIDE_COMPILER_PARAMS, WIDE_VMEM_BUDGET_BYTES, tile_bytes)
 
 _NEG = -1e30  # finite -inf stand-in: keeps the running max
 #                            NaN-free when a tile (or a whole vocab shard)
@@ -79,33 +88,39 @@ _NEG = -1e30  # finite -inf stand-in: keeps the running max
 
 def _vmem_cost(bn: int, bv: int, d: int, el: int, out_el: int,
                kernel: str) -> int:
-    """Scoped-VMEM bytes of one ``kernel`` ("fwd" | "dh" | "dw") step.
+    """Scoped-VMEM bytes of one ``kernel`` ("fwd" | "bwd") step.
 
     Blocks count twice (double-buffered in and out), scratch once, and the
     f32 logit tile four times (it, its iota/mask and the exp/dlogits
-    temporaries). dH and dW also hold an f32 cast and an iota mask of the
-    operand they zero (w for dH, h for dW) next to their f32 accumulator.
+    temporaries). The backward adds its resident f32 dW tile, the two f32
+    dH staging slots and the dH contraction's result, and the w block
+    transposed for that contraction. Compiled for v5e at the tiles of the
+    Qwen2-7B and Granite-3.0-8B heads, the kernel takes 4–5 MiB less than
+    this count.
     """
     h, w = tile_bytes(bn, d, el), tile_bytes(d, bv, el)
     vec = tile_bytes(bn, 1, 4)          # labels / lse / ll / gl row vectors
     logits = 4 * tile_bytes(bn, bv, 4)
     if kernel == "fwd":
         return 2 * (h + w + 3 * vec) + 3 * vec + logits
-    if kernel == "dh":
-        return (2 * (h + w + 3 * vec + tile_bytes(bn, d, out_el))
-                + tile_bytes(bn, d, 4) + 2 * tile_bytes(d, bv, 4) + logits)
     return (2 * (h + w + 3 * vec + tile_bytes(d, bv, out_el))
-            + tile_bytes(d, bv, 4) + 2 * tile_bytes(bn, d, 4) + logits)
+            + tile_bytes(d, bv, 4) + 3 * tile_bytes(bn, d, 4) + w + logits)
+
+
+_BUDGET = {"fwd": VMEM_BUDGET_BYTES, "bwd": WIDE_VMEM_BUDGET_BYTES}
 
 
 def _pick_blocks(n: int, d: int, v: int, block=None, *, el_bytes: int = 4,
                  out_bytes: int = 4, kernel: str = "fwd"):
     """(bn, bv) tile for one kernel, clamped to the (padded) problem.
 
-    The token tile bn is the HBM-reuse lever: w streams through HBM once
-    per token tile (forward/dH), so tiles start at 2048 x 2048 and shrink
-    until :func:`_vmem_cost` fits the scoped-VMEM budget — bv first down
-    to 512, then bn down to 256, then whichever is larger, to the
+    Tiles start at 2048 x 2048 and shrink until :func:`_vmem_cost` fits
+    the kernel's scoped-VMEM budget, the HBM-reuse lever last. The
+    forward streams w once per token tile, so bv shrinks first, down to
+    512, then bn down to 256. The backward streams h and the f32 dH once
+    per vocab tile, so bn shrinks first, down to 256, then bv down to 512
+    (a v5e step of 6·bn·bv·D FLOPs over ~10·bn·D bytes stays
+    compute-bound from bv = 512). Then whichever is larger shrinks, to the
     (32, 128) hardware tiling floor.
     """
     if block is not None:
@@ -114,9 +129,19 @@ def _pick_blocks(n: int, d: int, v: int, block=None, *, el_bytes: int = 4,
         bn = min(2048, -(-n // 32) * 32)
         bv = min(2048, -(-v // 128) * 128)
         fits = lambda bn, bv: _vmem_cost(bn, bv, d, el_bytes, out_bytes,
-                                         kernel) <= VMEM_BUDGET_BYTES
+                                         kernel) <= _BUDGET[kernel]
+
+        def shrink_bv():
+            if kernel == "bwd" and bn > 256:
+                return False
+            if bv > 512:
+                return True
+            if bn > 256:
+                return False
+            return bv > 128 and bv >= bn
+
         while not fits(bn, bv) and (bn > 32 or bv > 128):
-            if bv > 512 or (bn <= 256 and bv > 128 and bv >= bn):
+            if shrink_bv():
                 bv //= 2
             else:
                 bn = max(32, bn // 2 // 32 * 32)
@@ -125,7 +150,7 @@ def _pick_blocks(n: int, d: int, v: int, block=None, *, el_bytes: int = 4,
     return bn, bv
 
 
-def _logit_tile(h_ref, w_ref, wt: bool):
+def _logit_tile(h, w, wt: bool):
     """(bn, bv) f32 logit tile; ``wt`` statically selects the w layout.
 
     Untied: w block (d, bv), plain ``h @ w``. Transposed (tied): w block
@@ -133,16 +158,14 @@ def _logit_tile(h_ref, w_ref, wt: bool):
     systolic array just streams w row-major.
     """
     if wt:
-        return jax.lax.dot_general(h_ref[...], w_ref[...],
-                                   (((1,), (1,)), ((), ())),
+        return jax.lax.dot_general(h, w, (((1,), (1,)), ((), ())),
                                    preferred_element_type=jnp.float32)
-    return jnp.dot(h_ref[...], w_ref[...],
-                   preferred_element_type=jnp.float32)
+    return jnp.dot(h, w, preferred_element_type=jnp.float32)
 
 
 def _w_spec(d, bv, wt: bool, transpose_grid: bool = False):
     """BlockSpec for the w operand: (d, bv) tiles or (bv, d) when ``wt``."""
-    if transpose_grid:  # dW grid is (vocab, token): j is program_id(0)
+    if transpose_grid:  # backward grid is (vocab, token): j is program_id(0)
         if wt:
             return pl.BlockSpec((bv, d), lambda j, i: (j, 0))
         return pl.BlockSpec((d, bv), lambda j, i: (0, j))
@@ -182,7 +205,7 @@ def _fwd_kernel(h_ref, w_ref, lab_ref, off_ref, lse_ref, ll_ref,
         s_acc[...] = jnp.zeros_like(s_acc)
         ll_acc[...] = jnp.zeros_like(ll_acc)
 
-    logits = _logit_tile(h_ref, w_ref, wt)
+    logits = _logit_tile(h_ref[...], w_ref[...], wt)
     col, vmask = _col_masks(off_ref[0, 0], j, bv, v_local, vocab_size,
                             logits.shape, 1)
     logits = jnp.where(vmask, logits, _NEG)
@@ -239,145 +262,135 @@ def xent_fwd(h, w, labels, *, vocab_size: int, col_offset=0, block=None,
 
 
 # --------------------------------------------------------------------------
-# backward: dH from (softmax - onehot) @ w^T, same tiling as forward
+# backward: one logit tile -> dlogits -> both dW (resident) and dH (in HBM)
 # --------------------------------------------------------------------------
 
-def _dh_kernel(h_ref, w_ref, lab_ref, lse_ref, gl_ref, off_ref, dh_ref,
-               acc_ref, *, n_v_tiles, bv, v_local, vocab_size, wt):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    logits = _logit_tile(h_ref, w_ref, wt)
-    col, vmask = _col_masks(off_ref[0, 0], j, bv, v_local, vocab_size,
-                            logits.shape, 1)
-    p = jnp.where(vmask, jnp.exp(logits - lse_ref[...]), 0.0)
-    dlog = (p - jnp.where((col == lab_ref[...]) & vmask, 1.0, 0.0)) \
-        * gl_ref[...]
-    # zero w on masked columns: dlog is exactly 0 there, but undefined w
-    # lanes (remainder tiles) would still poison the product (0 * NaN).
-    # Transposed layout: the masked vocab ids run along w's *rows*.
-    if wt:
-        _, wmask = _col_masks(off_ref[0, 0], j, bv, v_local, vocab_size,
-                              (bv, w_ref.shape[1]), 0)
-        w_eff = jnp.where(wmask, w_ref[...].astype(jnp.float32), 0.0)
-        contract = (((1,), (0,)), ((), ()))
-    else:
-        _, wmask = _col_masks(off_ref[0, 0], j, bv, v_local, vocab_size,
-                              (w_ref.shape[0], bv), 1)
-        w_eff = jnp.where(wmask, w_ref[...].astype(jnp.float32), 0.0)
-        contract = (((1,), (1,)), ((), ()))
-    acc_ref[...] += jax.lax.dot_general(
-        dlog, w_eff, contract, preferred_element_type=jnp.float32)
-
-    @pl.when(j == n_v_tiles - 1)
-    def _emit():
-        dh_ref[...] = acc_ref[...].astype(dh_ref.dtype)
-
-
-def xent_bwd_dh(h, w, labels, lse, gl, *, vocab_size: int, col_offset=0,
-                block=None, interpret: bool = True, out_dtype=jnp.float32,
-                transposed: bool = False):
-    """dH (N, D): gl-weighted (softmax - onehot) contracted with w.
-
-    ``gl`` (N,) f32 is the per-token upstream cotangent (already 0 for
-    masked labels); ``lse`` the forward's (globally combined) logsumexp.
-    Under vocab sharding the result is a partial sum over local columns —
-    the caller psums it over the vocab mesh axes. ``transposed``: w is the
-    tied (V, D) embedding.
-    """
-    n, d = h.shape
-    v = w.shape[0] if transposed else w.shape[1]
-    bn, bv = _pick_blocks(n, d, v, block, el_bytes=h.dtype.itemsize,
-                          out_bytes=jnp.dtype(out_dtype).itemsize,
-                          kernel="dh")
-    grid = (pl.cdiv(n, bn), pl.cdiv(v, bv))
-    off = jnp.asarray(col_offset, jnp.int32).reshape(1, 1)
-    tok = pl.BlockSpec((bn, 1), lambda i, j: (i, 0))
-    return pl.pallas_call(
-        functools.partial(_dh_kernel, n_v_tiles=grid[1], bv=bv, v_local=v,
-                          vocab_size=vocab_size, wt=transposed),
-        grid=grid,
-        in_specs=[pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
-                  _w_spec(d, bv, transposed),
-                  tok, tok, tok,
-                  pl.BlockSpec((1, 1), lambda i, j: (0, 0),
-                               memory_space=pltpu.SMEM)],
-        out_specs=pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, d), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32)],
-        interpret=interpret,
-        compiler_params=COMPILER_PARAMS,
-        name="xent_bwd_dh",
-    )(h, w, labels.reshape(n, 1), lse.reshape(n, 1), gl.reshape(n, 1), off)
-
-
-# --------------------------------------------------------------------------
-# backward: dW tile resident while token tiles stream (transposed grid)
-# --------------------------------------------------------------------------
-
-def _dw_kernel(w_ref, h_ref, lab_ref, lse_ref, gl_ref, off_ref, dw_ref,
-               acc_ref, *, n_t_tiles, bn, bv, v_local, n_tokens, vocab_size,
-               wt):
+def _bwd_kernel(w_ref, h_ref, lab_ref, lse_ref, gl_ref, off_ref, dw_ref,
+                dh_hbm, acc_ref, dh_buf, sem, *, n_v_tiles, n_t_tiles, bn,
+                bv, v_local, n_tokens, vocab_size, wt):
     j, i = pl.program_id(0), pl.program_id(1)
+    step = j * n_t_tiles + i
+    slot = step % 2
+    rows = pl.ds(pl.multiple_of(i * bn, bn), bn)
+
+    def copy(k, out):  # dH staging slot k <-> this token tile's HBM rows
+        if out:
+            return pltpu.make_async_copy(dh_buf.at[k], dh_hbm.at[rows],
+                                         sem.at[1, k])
+        return pltpu.make_async_copy(dh_hbm.at[rows], dh_buf.at[k],
+                                     sem.at[0, k])
+
+    def wait_prev_write():  # same byte count as the previous step's write
+        copy(1 - slot, True).wait()
+
+    if n_t_tiles == 1:  # this step re-reads the rows the last one wrote
+        pl.when(step > 0)(wait_prev_write)
+
+    @pl.when(j > 0)
+    def _fetch():
+        copy(slot, False).start()
 
     @pl.when(i == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    logits = _logit_tile(h_ref, w_ref, wt)
+    # A remainder tile's lanes past w's width and rows past N are undefined
+    # memory (0 * NaN = NaN in either contraction): zero them in the block
+    # buffers, in the operands' own dtype, on those tiles alone. Zeroed h,
+    # lse and gl rows then give dlogits rows of exactly 0.
+    v_rem, n_rem = v_local % bv, n_tokens % bn
+    if v_rem:
+        @pl.when(j == n_v_tiles - 1)
+        def _clear_lanes():
+            if wt:
+                w_ref[v_rem:, :] = jnp.zeros((bv - v_rem, w_ref.shape[1]),
+                                             w_ref.dtype)
+            else:
+                w_ref[:, v_rem:] = jnp.zeros((w_ref.shape[0], bv - v_rem),
+                                             w_ref.dtype)
+    if n_rem:
+        @pl.when(i == n_t_tiles - 1)
+        def _clear_rows():
+            for ref in (h_ref, lse_ref, gl_ref):
+                ref[n_rem:, :] = jnp.zeros((bn - n_rem, ref.shape[1]),
+                                           ref.dtype)
+
+    h, w = h_ref[...], w_ref[...]
+    logits = _logit_tile(h, w, wt)
     col, vmask = _col_masks(off_ref[0, 0], j, bv, v_local, vocab_size,
                             logits.shape, 1)
-    row = i * bn + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
-    tokmask = row < n_tokens
-    p = jnp.where(vmask & tokmask, jnp.exp(logits - lse_ref[...]), 0.0)
-    # token-remainder rows carry undefined lse/gl; unlike forward/dH the
-    # token axis is contracted here, so both operands are zeroed past N
-    dlog = jnp.where(tokmask,
-                     (p - jnp.where((col == lab_ref[...]) & vmask, 1.0, 0.0))
-                     * gl_ref[...], 0.0)
-    hrow = i * bn + jax.lax.broadcasted_iota(jnp.int32, h_ref.shape, 0)
-    h_eff = jnp.where(hrow < n_tokens, h_ref[...].astype(jnp.float32), 0.0)
-    if wt:
-        # (V, D)-layout accumulator: dW[v, :] = sum_n dlog[n, v] * h[n, :]
-        # — invalid vocab lanes have dlog exactly 0, so their rows stay 0
+    p = jnp.where(vmask, jnp.exp(logits - lse_ref[...]), 0.0)
+    dlog = (p - jnp.where((col == lab_ref[...]) & vmask, 1.0, 0.0)) \
+        * gl_ref[...]
+    # dlogits enter both contractions in f32 beside the operands' dtype: on
+    # v5e that runs at the rate of bf16 dlogits and rounds nothing more
+    tok = (((0,), (0,)), ((), ()))
+    if wt:  # w and dW (bv, D): dW[v, :] = sum_n dlog[n, v] h[n, :]
         acc_ref[...] += jax.lax.dot_general(
-            dlog, h_eff, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            dlog, h, tok, preferred_element_type=jnp.float32)
+        dh = jax.lax.dot_general(dlog, w, (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
     else:
         acc_ref[...] += jax.lax.dot_general(
-            h_eff, dlog, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            h, dlog, tok, preferred_element_type=jnp.float32)
+        dh = jax.lax.dot_general(dlog, w, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+
+    @pl.when(j == 0)
+    def _first():
+        dh_buf[slot] = dh
+
+    @pl.when(j > 0)
+    def _add():
+        copy(slot, False).wait()
+        dh_buf[slot] += dh
+
+    copy(slot, True).start()
+    if n_t_tiles > 1:  # the next step stages into that write's slot
+        pl.when(step > 0)(wait_prev_write)
+
+    @pl.when(step == n_v_tiles * n_t_tiles - 1)
+    def _drain():
+        copy(slot, True).wait()
 
     @pl.when(i == n_t_tiles - 1)
     def _emit():
         dw_ref[...] = acc_ref[...].astype(dw_ref.dtype)
 
 
-def xent_bwd_dw(h, w, labels, lse, gl, *, vocab_size: int, col_offset=0,
-                block=None, interpret: bool = True, out_dtype=jnp.float32,
-                transposed: bool = False):
-    """dW: h^T contracted with the gl-weighted (softmax - onehot).
+def xent_bwd(h, w, labels, lse, gl, *, vocab_size: int, col_offset=0,
+             block=None, interpret: bool = True, dw_dtype=jnp.float32,
+             transposed: bool = False):
+    """(dH, dW) of the gl-weighted cross-entropy, from one logit pass.
 
-    Emitted in w's own layout — (D, V), or (V, D) when ``transposed`` so
-    the tied head's gradient lands directly on the embedding storage.
-    Under batch sharding the result is a partial sum over local tokens —
-    the caller psums it over the token mesh axes.
+    ``gl`` (N,) f32 is the per-token upstream cotangent (already 0 for
+    masked labels); ``lse`` the forward's (globally combined) logsumexp.
+    The grid runs vocab tiles outer and token tiles inner: each step forms
+    one (bn, bv) logit tile and its dlogits, accumulates ``h^T @ dlogits``
+    into the vocab tile's dW, resident in VMEM until the last token tile
+    (then written once in w's layout, in ``dw_dtype``), and adds
+    ``dlogits @ w^T`` to the token tile's f32 dH rows in HBM, which the
+    kernel streams itself through two VMEM slots (the read overlaps the
+    step's contractions, the write the next step's).
+
+    dH comes back (N, D) f32; under vocab sharding it is a partial sum
+    over local columns and dW one over local tokens — the caller psums
+    them over the mesh axes and rounds. ``transposed``: w (and dW) is the
+    tied (V, D) embedding.
     """
     n, d = h.shape
     v = w.shape[0] if transposed else w.shape[1]
     bn, bv = _pick_blocks(n, d, v, block, el_bytes=h.dtype.itemsize,
-                          out_bytes=jnp.dtype(out_dtype).itemsize,
-                          kernel="dw")
+                          out_bytes=jnp.dtype(dw_dtype).itemsize,
+                          kernel="bwd")
     grid = (pl.cdiv(v, bv), pl.cdiv(n, bn))
     off = jnp.asarray(col_offset, jnp.int32).reshape(1, 1)
     tok = pl.BlockSpec((bn, 1), lambda j, i: (i, 0))
     wspec = _w_spec(d, bv, transposed, transpose_grid=True)
-    return pl.pallas_call(
-        functools.partial(_dw_kernel, n_t_tiles=grid[1], bn=bn, bv=bv,
-                          v_local=v, n_tokens=n, vocab_size=vocab_size,
+    dw, dh = pl.pallas_call(
+        functools.partial(_bwd_kernel, n_v_tiles=grid[0],
+                          n_t_tiles=grid[1], bn=bn, bv=bv, v_local=v,
+                          n_tokens=n, vocab_size=vocab_size,
                           wt=transposed),
         grid=grid,
         in_specs=[wspec,
@@ -385,12 +398,17 @@ def xent_bwd_dw(h, w, labels, lse, gl, *, vocab_size: int, col_offset=0,
                   tok, tok, tok,
                   pl.BlockSpec((1, 1), lambda j, i: (0, 0),
                                memory_space=pltpu.SMEM)],
-        out_specs=wspec,
-        out_shape=jax.ShapeDtypeStruct((v, d) if transposed else (d, v),
-                                       out_dtype),
+        out_specs=[wspec, pl.BlockSpec(memory_space=pl.ANY)],
+        # dH rows padded to whole token tiles: the kernel's DMAs are static
+        out_shape=[jax.ShapeDtypeStruct((v, d) if transposed else (d, v),
+                                        dw_dtype),
+                   jax.ShapeDtypeStruct((grid[1] * bn, d), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bv, d) if transposed else (d, bv),
-                                   jnp.float32)],
+                                   jnp.float32),
+                        pltpu.VMEM((2, bn, d), jnp.float32),
+                        pltpu.SemaphoreType.DMA((2, 2))],
         interpret=interpret,
-        compiler_params=COMPILER_PARAMS,
-        name="xent_bwd_dw",
+        compiler_params=WIDE_COMPILER_PARAMS,
+        name="xent_bwd",
     )(w, h, labels.reshape(n, 1), lse.reshape(n, 1), gl.reshape(n, 1), off)
+    return dh[:n], dw

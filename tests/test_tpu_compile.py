@@ -5,7 +5,8 @@ tile: only the chip's compiler can. It is installed here and compiles for
 a described (not attached) ``v5e:2x2`` topology, so these tests hand each
 kernel ``ShapeDtypeStruct`` operands placed on one described chip and
 compile it. Shapes are those of the paper's LLaMA-1B/7B training steps:
-the xent head at N=2048 tokens and V=32000, attention at head_dim 64
+the xent head at N=2048 tokens and V=32000 (and the backward at the
+benchmark cells' Qwen2-7B and Granite-3.0-8B heads), attention at head_dim 64
 (T=256) and 128 (T=4096) and packed 16k rows at Qwen2-7B's heads, the
 SCALE update on the stacked MLP weight of
 LLaMA-1B and the LM-head momentum.
@@ -56,8 +57,10 @@ N, V = 2048, 32000
 
 @pytest.mark.parametrize("transposed", [False, True], ids=["untied", "tied"])
 @pytest.mark.parametrize("d", [512, 2048, 4096])
-@pytest.mark.parametrize("kernel", ["fwd", "dh", "dw"])
+@pytest.mark.parametrize("kernel", ["fwd", "bwd_bf16", "bwd_f32"])
 def test_xent_compiles(one_chip, kernel, d, transposed):
+    """The forward, and the backward with a bf16 dW (one chip) or an f32
+    dW (the partial sum a mesh axis psums)."""
     w = ((V, d) if transposed else (d, V), BF16)
     common = [((N, d), BF16), w, ((N,), I32)]
     kw = dict(vocab_size=V, interpret=False, transposed=transposed)
@@ -65,12 +68,28 @@ def test_xent_compiles(one_chip, kernel, d, transposed):
         _compile(lambda h, w, lab: xk.xent_fwd(h, w, lab, **kw), one_chip,
                  *common)
         return
-    bwd = xk.xent_bwd_dh if kernel == "dh" else xk.xent_bwd_dw
-    # bf16 out on one chip; f32 partial sums when a mesh axis psums them
-    for out in (BF16, F32):
-        _compile(lambda h, w, lab, lse, gl: bwd(h, w, lab, lse, gl,
-                                                out_dtype=out, **kw),
-                 one_chip, *common, ((N,), F32), ((N,), F32))
+    out = BF16 if kernel == "bwd_bf16" else F32
+    _compile(lambda h, w, lab, lse, gl: xk.xent_bwd(h, w, lab, lse, gl,
+                                                     dw_dtype=out, **kw),
+             one_chip, *common, ((N,), F32), ((N,), F32))
+
+
+@pytest.mark.parametrize("n,d,v,dw_dtype,transposed",
+                         [(16384, 3584, 152064, BF16, False),
+                          (8192, 4096, 49280, F32, True)],
+                         ids=["qwen2-7b", "granite-3-8b"])
+def test_xent_bwd_compiles_at_cell_heads(one_chip, n, d, v, dw_dtype,
+                                         transposed):
+    """The backward at the benchmark cells' heads, where its tiles fill
+    most of the wide scoped-VMEM limit: Qwen2-7B untied with a bf16 dW on
+    one chip, Granite-3.0-8B tied with the f32 dW its token psum takes."""
+    w = ((v, d) if transposed else (d, v), BF16)
+    kw = dict(vocab_size=v, interpret=False, transposed=transposed,
+              dw_dtype=dw_dtype)
+    _compile(lambda h, w, lab, lse, gl: xk.xent_bwd(h, w, lab, lse, gl,
+                                                     **kw),
+             one_chip, ((n, d), BF16), w, ((n,), I32), ((n,), F32),
+             ((n,), F32))
 
 
 @pytest.mark.parametrize("B,S,H,K,hd", [(16, 256, 32, 32, 64),

@@ -282,15 +282,74 @@ def test_vocab_shard_remainder_tiles_masked():
     rdh, rdw = jax.grad(
         lambda h, w: jnp.sum(xref.losses(h, w, lab, VS) * gl),
         argnums=(0, 1))(h, w)
-    dh = sum(xk.xent_bwd_dh(h, w[:, a:b], lab, lse_g, gl, vocab_size=VS,
-                            col_offset=a, block=(32, 128))
-             for a, b in halves)
-    dw = jnp.concatenate(
-        [xk.xent_bwd_dw(h, w[:, a:b], lab, lse_g, gl, vocab_size=VS,
-                        col_offset=a, block=(32, 128)) for a, b in halves],
-        axis=1)
+    grads = [xk.xent_bwd(h, w[:, a:b], lab, lse_g, gl, vocab_size=VS,
+                         col_offset=a, block=(32, 128)) for a, b in halves]
+    dh = sum(g[0] for g in grads)
+    dw = jnp.concatenate([g[1] for g in grads], axis=1)
     np.testing.assert_allclose(np.asarray(dh), np.asarray(rdh), atol=1e-4)
     np.testing.assert_allclose(np.asarray(dw), np.asarray(rdw), atol=1e-4)
+
+
+@pytest.mark.parametrize("dw_dtype", DTYPES, ids=["dw_f32", "dw_bf16"])
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+@pytest.mark.parametrize("transposed", [False, True],
+                         ids=["untied", "transposed"])
+def test_xent_bwd_matches_reference_grads(transposed, dtype, dw_dtype):
+    """The one backward kernel's (dH, dW) against the reference's
+    gradients: padded vocabulary (300 of 320), remainder tiles on both
+    axes (70 tokens over 32-row tiles, 320 columns over 128-lane tiles),
+    masked labels and tokens whose upstream weight is 0 (their dH rows
+    must come back exactly 0). dH is f32 whatever the operands; dW comes
+    back in ``dw_dtype`` and in w's layout."""
+    from repro.kernels.xent import xent as xk
+    n, d, V, VS = 70, 64, 320, 300
+    ks = jax.random.split(jax.random.PRNGKey(16), 4)
+    h = jax.random.normal(ks[0], (n, d)).astype(dtype)
+    w = jax.random.normal(ks[1], (d, V)).astype(dtype)
+    lab = jax.random.randint(ks[2], (n,), -1, VS)
+    keep = jnp.arange(n) % 3 != 0                    # zero upstream weights
+    gl = jnp.abs(jax.random.normal(ks[3], (n,))) * (lab >= 0) * keep
+    lse, _ = xref.lse_ll(h, w, lab, VS)
+    rdh, rdw = jax.grad(
+        lambda h, w: jnp.sum(xref.losses(h, w, lab, VS) * gl),
+        argnums=(0, 1))(h.astype(jnp.float32), w.astype(jnp.float32))
+    dh, dw = xk.xent_bwd(h, w.T if transposed else w, lab, lse, gl,
+                         vocab_size=VS, block=(32, 128), dw_dtype=dw_dtype,
+                         transposed=transposed)
+    assert dh.shape == (n, d) and dh.dtype == jnp.float32
+    assert dw.dtype == dw_dtype
+    assert dw.shape == ((V, d) if transposed else (d, V))
+    dw = dw.T if transposed else dw
+    np.testing.assert_array_equal(np.asarray(dh)[np.asarray(gl) == 0], 0.0)
+    # relative to the gradient's scale: a bf16 dW is rounded by up to
+    # 2^-9 of it; a wrong mask or tile is O(1)
+    for got, ref, rel in ((dh, rdh, 2e-5),
+                          (dw, rdw, 4e-3 if dw_dtype == jnp.bfloat16
+                           else 2e-5)):
+        ref = np.asarray(ref)
+        np.testing.assert_allclose(np.asarray(got, np.float32), ref,
+                                   atol=rel * np.abs(ref).max())
+
+
+@pytest.mark.parametrize("n,d,v,dw_dtype,transposed",
+                         [(16384, 3584, 152064, jnp.bfloat16, False),
+                          (8192, 4096, 49280, jnp.float32, True)],
+                         ids=["qwen2-7b", "granite-3-8b"])
+def test_xent_bwd_tiles_fit_wide_vmem(n, d, v, dw_dtype, transposed):
+    """At the benchmark cells' heads the backward's tile keeps bv >= 512
+    (compute-bound streaming of h and the f32 dH) and its VMEM count
+    fits the wide budget, which sits under the wide limit, under v5e's
+    128 MiB."""
+    from repro.kernels import vmem
+    from repro.kernels.xent import xent as xk
+    out = jnp.dtype(dw_dtype).itemsize
+    bn, bv = xk._pick_blocks(n, d, v, el_bytes=2, out_bytes=out,
+                             kernel="bwd")
+    assert bv >= 512 and bn >= 128
+    assert xk._vmem_cost(bn, bv, d, 2, out, "bwd") \
+        <= vmem.WIDE_VMEM_BUDGET_BYTES
+    assert vmem.WIDE_VMEM_BUDGET_BYTES < vmem.WIDE_VMEM_LIMIT_BYTES \
+        < 128 << 20
 
 
 # ---- sharded matrix on a forced 8-device host mesh ------------------------
