@@ -15,13 +15,12 @@ def main() -> None:
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default="",
                     help="comma list: norms,memory,pretrain,optimizers,"
-                         "throughput,variance,roofline,fused,xent")
+                         "throughput,variance,fused,xent")
     args = ap.parse_args()
     quick = not args.full
 
     from . import (fused_update, memory_table, norm_timing, optimizer_bench,
-                   pretrain_proxy, roofline, throughput, variance_analysis,
-                   xent_fused)
+                   pretrain_proxy, throughput, variance_analysis, xent_fused)
     sections = {
         "norms": norm_timing,
         "memory": memory_table,
@@ -29,7 +28,6 @@ def main() -> None:
         "optimizers": optimizer_bench,
         "throughput": throughput,
         "variance": variance_analysis,
-        "roofline": roofline,
         "fused": fused_update,
         "xent": xent_fused,
     }

@@ -4,8 +4,8 @@ a few hundred steps with SCALE, with checkpointing + auto-resume.
   PYTHONPATH=src python examples/pretrain.py --arch llama-60m --steps 300
   # kill it at any point, re-run with the same command: it resumes.
 
-This is the same production path the multi-pod dry-run lowers — on a TPU
-slice the identical code shards over the (data, model) mesh.
+This is the production path — on a TPU slice the identical code shards
+over the (data, model) mesh.
 """
 import argparse
 

@@ -1,5 +1,5 @@
 """Serving example: batched prefill + greedy decode with a persistent KV
-cache — the same prefill/decode steps the inference dry-run cells lower.
+cache, through the serving-step factories.
 
   PYTHONPATH=src python examples/serve.py --arch qwen2-7b
 (uses the reduced smoke config on CPU; on a TPU slice drop --smoke logic

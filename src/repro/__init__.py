@@ -7,7 +7,7 @@ Subpackages:
   data       — deterministic shard-aware token pipeline
   training   — train/serve step factories, grad accumulation, remat
   checkpoint — sharded zstd checkpoints with auto-resume
-  launch     — production meshes, multi-pod dry-run, roofline analysis
+  launch     — meshes, the training CLI, compiled-step introspection
   configs    — assigned architecture configs (``--arch <id>``)
   kernels    — Pallas TPU kernels for the optimizer hot path
 """

@@ -172,9 +172,9 @@ def check_dispatch_contract():
     """CX004: executable probe of the reduce-axis derivation.
 
     Col-kind norms reduce over rows, so the cross-shard psum must run
-    over the axes sharding dim 1 of the padded (L, m, n) layout
-    (``plan.spec3[1]``); row-kind over dim 2. A synthetic plan makes the
-    mapping observable without any mesh.
+    over the axes sharding the ``rows`` role of the padded (L, m, n)
+    layout; row-kind over ``cols``. A synthetic plan makes the mapping
+    observable without any mesh.
     """
     out = []
     try:
@@ -184,13 +184,14 @@ def check_dispatch_contract():
                         f"could not import dispatch for the dynamic "
                         f"reduce-axis probe: {e!r}")]
     red = getattr(_d, "_red_axes", None)
-    plan_cls = getattr(_d, "ShardPlan", None)
+    plan_cls = getattr(_d, "Plan", None)
     if red is None or plan_cls is None:
         return [Finding("CX004", "src/repro/kernels/dispatch.py", 0,
-                        "dispatch no longer exposes _red_axes/ShardPlan; "
+                        "dispatch no longer exposes _red_axes/Plan; "
                         "update the CX004 probe alongside the refactor")]
     try:
-        plan = plan_cls(None, ((), ("row_ax",), ("col_ax",)))
+        plan = plan_cls(None, (("rows", ("row_ax",)),
+                               ("cols", ("col_ax",))))
         got_col = tuple(red(plan, "col"))
         got_row = tuple(red(plan, "row"))
     except Exception as e:
@@ -200,12 +201,12 @@ def check_dispatch_contract():
         out.append(Finding(
             "CX004", "src/repro/kernels/dispatch.py", 0,
             f"col-kind reduce axes must be the row-dim sharding axes "
-            f"(spec3[1]); got {got_col!r}"))
+            f"(role 'rows'); got {got_col!r}"))
     if got_row != ("col_ax",):
         out.append(Finding(
             "CX004", "src/repro/kernels/dispatch.py", 0,
             f"row-kind reduce axes must be the col-dim sharding axes "
-            f"(spec3[2]); got {got_row!r}"))
+            f"(role 'cols'); got {got_row!r}"))
     return out
 
 

@@ -1,6 +1,2 @@
-from .base import LM_SHAPES, ShapeSpec, cell_config, supports_long_context
-from .registry import (ARCH_IDS, LLAMA_PAPER, get_arch, get_cell, get_shapes,
-                       iter_cells)
-__all__ = ["LM_SHAPES", "ShapeSpec", "cell_config", "supports_long_context",
-           "ARCH_IDS", "LLAMA_PAPER", "get_arch", "get_cell", "get_shapes",
-           "iter_cells"]
+from .registry import ARCH_IDS, LLAMA_PAPER, get_arch
+__all__ = ["ARCH_IDS", "LLAMA_PAPER", "get_arch"]

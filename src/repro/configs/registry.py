@@ -6,8 +6,6 @@ import importlib
 
 from repro.models import ModelConfig
 
-from .base import LM_SHAPES, ShapeSpec, cell_config, supports_long_context
-
 ARCH_IDS = (
     "deepseek-67b",
     "qwen2-7b",
@@ -73,24 +71,3 @@ def get_arch(arch_id: str, smoke: bool = False) -> ModelConfig:
     raise KeyError(f"unknown arch {arch_id!r}; options: "
                    f"{ARCH_IDS + tuple(LLAMA_PAPER) + tuple(PAPER_EXTRA)}")
 
-
-def get_shapes(arch_id: str) -> tuple:
-    return LM_SHAPES
-
-
-def iter_cells(include_skipped: bool = False):
-    """All (arch_id, ShapeSpec, runnable) dry-run cells."""
-    for arch_id in ARCH_IDS:
-        cfg = get_arch(arch_id)
-        for shape in LM_SHAPES:
-            runnable = not (shape.subquadratic_only
-                            and not supports_long_context(cfg))
-            if runnable or include_skipped:
-                yield arch_id, shape, runnable
-
-
-def get_cell(arch_id: str, shape_name: str):
-    """(adapted ModelConfig, ShapeSpec) for one dry-run cell."""
-    cfg = get_arch(arch_id)
-    shape = {s.name: s for s in LM_SHAPES}[shape_name]
-    return cell_config(cfg, shape), shape

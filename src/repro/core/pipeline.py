@@ -292,7 +292,7 @@ def build_pipeline(
         return (fused and kind is not None and not st.adam
                 and not st.adams and st.project is None
                 and not st.standardize and not st.nesterov
-                and _kd.supported(shape, kind, mode))
+                and mode != "off" and _kd.supported(shape, kind))
 
     def _flat_with_labels(tree):
         leaves, treedef = jax.tree_util.tree_flatten(tree)

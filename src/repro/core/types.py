@@ -15,12 +15,12 @@ update tree) may additionally provide ``update_params``:
 
     params, state = tx.update_params(grads, state, params)
 
-The field defaults to ``None``; callers (e.g. the trainer) feature-detect it
-and fall back to the classic ``update`` + ``apply_updates`` sequence.
+The field defaults to ``None``; callers (e.g. the trainer) then use the
+classic ``update`` + ``apply_updates`` sequence.
 
-``update_params`` implementations may additionally accept two optional
-keyword arguments, which callers also feature-detect (via
-``inspect.signature``) before passing:
+Every ``update_params`` (all are built by :mod:`repro.core.pipeline`)
+accepts two optional keyword arguments, and the trainer passes them
+without asking: ``shardings`` under a mesh, ``grad_scale`` when it clips.
 
   * ``shardings`` — pytree of per-parameter ``jax.sharding.NamedSharding``
     (same structure as params). Optimizers whose hot path runs custom

@@ -126,27 +126,56 @@ write path (bitwise-equal to update+apply) even when never fused.
 
 Sharded dispatch (pjit meshes)
 ------------------------------
-A bare ``pallas_call`` has no SPMD partitioning rule: under a ``("data",
-"model")`` mesh the kernel would see only its local shard and compute the
-per-column sums-of-squares over a *fraction* of the rows — silently
-normalizing by the wrong norm. Entry points therefore accept the array's
-``NamedSharding`` (derived by the trainer from ``models/sharding.Rules``)
-and, when any dim is actually sharded, wrap the kernels in ``shard_map``:
+A bare ``pallas_call`` has no SPMD partitioning rule: under a mesh a kernel
+sees only its local shard, and the norm kernels would sum squares over a
+*fraction* of the rows — silently normalizing by the wrong norm. The entry
+points therefore take their operands' ``NamedSharding`` (derived by the
+trainer from ``models/sharding.Rules``) and, when a role is sharded, run
+the kernels under ``shard_map`` on each device's local shard. Each family
+names one role per operand dim; one planner (``_plan``) maps the roles to
+mesh axes as a :class:`Plan`, and one helper turns a plan and roles into
+PartitionSpecs:
 
-  * every kernel runs on its **local shard** (per-shard HBM passes only);
-  * the sum-of-squares reduction emits a **partial** per-slice result which
-    is ``lax.psum``-ed over exactly the mesh axes that shard the *reduce*
-    dim — for ``col`` norms the axes sharding the row dim (``d_in``, e.g.
-    the FSDP ``"data"`` axis under the default rules), for ``row`` norms
-    the axes sharding the column dim (``d_out``, e.g. ``"model"``). The
-    psum moves one per-slice vector (~1/256 of a matrix) over ICI, not the
-    matrix itself;
-  * the apply stage then consumes the now-global norms shard-locally.
+  =========  ===============================  =================================
+  family     roles, one per dim               collectives under a plan
+  =========  ===============================  =================================
+  updates    canon3 (L, m, n):                the per-slice sums of squares
+             (stack, rows, cols)              psum over the reduce dim's axes —
+                                              ``rows`` for col norms (FSDP
+                                              ``data``), ``cols`` for row norms
+                                              (``model``); one vector, not the
+                                              matrix, crosses the ICI
+  xent       h (tok, -[, -]); labels, lse     (lse, ll) pmax/psum over ``voc``
+             (tok[, -]); w (gathered, voc),   with a global column offset per
+             tied (V, D) w (voc, gathered)    shard; dH psums over ``voc``, dW
+                                              (in f32) over ``tok``
+  attention  q, k, v (batch, -, heads, -);    none: every (batch, head) pair is
+             lse (batch, heads, -);           device-local (the softmax reduces
+             segment ids (batch, -)           over the unsharded T; the GQA
+                                              group ratio holds per shard)
+  =========  ===============================  =================================
 
-Shardings whose reduce/batch dims do not divide the mesh axes (shard_map
-requires exact divisibility) and non-NamedSharding layouts fall back to the
-jnp reference, which GSPMD partitions correctly on its own. A replicated
-NamedSharding (no mesh axes mapped) takes the ordinary single-device path.
+``-`` is a dim that must not be sharded. ``gathered`` (xent's embed dim)
+may be sharded and is all-gathered at shard_map entry — the same gather
+GSPMD inserts for the unfused head matmul. The planner's rules, in order:
+
+  1. no operand has a sharding: the single-device path;
+  2. a sharding that is not a ``NamedSharding``, or two meshes: reference;
+  3. a ``-`` dim sharded (a sequence- or head_dim-sharded activation, the
+     ``cache_seq`` decode cache): reference;
+  4. a role sharded differently by two operands (q against kv): reference;
+  5. one mesh axis serving two roles (tokens and vocab — the lse/ll psum
+     would mix token shards): reference;
+  6. an axis not in the mesh, or a dim its axes do not divide (shard_map
+     needs exact divisibility): reference;
+  7. no role sharded (a replicated layout): the single-device path.
+
+The reference is the jnp path, which GSPMD partitions correctly on its
+own. Callers that own a memory-safe jnp path ask ``xent_route`` /
+``attn_route`` first and keep that path on "ref": ``models.model.lm_loss``
+its chunked scan (``xent_loss``'s own reference is the full-logit
+test-scale oracle), ``models.layers`` its blockwise scans
+(``flash_attention``'s reference delegates to those same layer functions).
 
 The ``REPRO_FUSED`` environment variable overrides the mode: ``auto``
 (default), ``interpret``, ``compiled``, or ``off`` (always use the jnp
@@ -167,13 +196,13 @@ failure re-raises: the chip must never silently run the reference.
 Kernel failures surface at trace/lower time (host-side), which is exactly
 where the guard sits.
 
-Entry points (scalar lr/beta/gscale may be traced schedule outputs). All
-accept ``gscale`` — a scalar multiplied into the gradient at read time
-inside the kernels, used by the trainer to fold the global-norm clip factor
-into the fused step without a separate full grad read+write. HBM passes
-count every full-matrix read/write, jnp-path counts in parentheses; the
-per-slice norm vector is negligible (see the accounting note in
-:mod:`repro.kernels.colnorm.colnorm`):
+Entry points (scalar lr/beta/gscale may be traced schedule outputs). The
+update ops accept ``gscale`` — a scalar multiplied into the gradient at
+read time inside the kernels, used by the trainer to fold the global-norm
+clip factor into the fused step without a separate full grad read+write.
+HBM passes count every full-matrix read/write, jnp-path counts in
+parentheses; the per-slice norm vector is negligible (see the accounting
+note in :mod:`repro.kernels.colnorm.colnorm`):
 
   ========================  =======================================  ======
   op                        computes                                 passes
@@ -184,54 +213,14 @@ per-slice norm vector is negligible (see the accounting note in
   ``momentum_norm_update``  m' = EMA(m, g); theta - lr*normalize(m') 6  (9)
   ========================  =======================================  ======
 
-Under a mesh the same counts hold *per shard* (each device streams only its
-1/N of every matrix). The theta writes in ``norm_update`` and
+Under a mesh the update counts hold *per shard* (each device streams only
+its 1/N of every matrix). The theta writes in ``norm_update`` and
 ``momentum_norm_update`` alias theta to the output, so with buffer donation
 (``donate_argnums`` on the train step) the apply stage allocates no fresh
-theta.
-
-Fused cross-entropy (``xent_loss``)
------------------------------------
-The LM-head loss is registered through the same machinery: ``xent_loss``
-is a ``custom_vjp`` whose forward/backward run the blockwise Pallas
-kernels in :mod:`repro.kernels.xent` (logits never materialize beyond a
-(token-tile, vocab-tile) VMEM block). Routing mirrors the update ops —
-compiled on TPU, interpret oracle elsewhere, ``REPRO_FUSED=off`` or an
-uncovered shape/sharding routes to the reference (callers that must stay
-memory-safe check ``xent_route`` first: the in-dispatch fallback is the
-*full-logit* test-scale oracle, while ``models.model.lm_loss`` keeps the
-chunked scan as the production jnp path). Sharded dispatch takes the
-hidden/head ``NamedSharding`` pair: tokens shard over the axes sharding
-h's leading (batch) dim, the vocab dim over w's column axes — each shard
-runs the kernels on its local (N/k, D) x (D, V/m) problem with a global
-column offset, then the per-shard (lse, ll) combine via ``pmax``/``psum``
-over the vocab axes exactly as the norm kernels psum column sums; dH
-psums over the vocab axes, dW over the token axes. w's embed-dim sharding
-is gathered at shard_map entry (the same all-gather GSPMD inserts for the
-unfused head matmul).
-
-Fused flash attention (``flash_attention``)
--------------------------------------------
-The attention hot path is registered the same way: ``flash_attention`` is
-a ``custom_vjp`` over the blockwise Pallas kernels in
-:mod:`repro.kernels.attention` (score tiles never leave VMEM; the
-backward recomputes them from the saved ``lse`` exactly like the jnp
-scan's custom_vjp). Routing mirrors the other ops — compiled on TPU,
-interpret oracle elsewhere, ``REPRO_FUSED=off`` or an uncovered
-shape/sharding routes to the reference. Callers that own a memory-safe
-jnp path check ``attn_route`` first and keep it (``models.layers`` keeps
-the blockwise ``lax.scan`` as the bitwise reference and
-``chunked_q_attention`` for the decode cache); the in-dispatch fallback
-delegates to those same layer implementations. The shard plan covers the
-**activation batch and head** mesh axes (the dims a
-``_plan_sharding``-style shard_map can express exactly): q and kv must
-shard batch/heads over identical axes — each device then runs its
-(B/n, S, H/m, hd) x (B/n, T, K/m, hd) problem with **no collectives at
-all** (the softmax reduces over the unsharded T, and the GQA group ratio
-is preserved per shard). Sequence- or head_dim-sharded layouts (e.g. the
-``cache_seq -> "model"`` decode cache) and GQA layouts where kv cannot
-shard like q (K not divisible by the head axes) fall back to the jnp
-path, which GSPMD partitions with its small lse all-reduces.
+theta. ``xent_loss`` and ``flash_attention`` are ``custom_vjp`` functions
+over the blockwise kernels in :mod:`repro.kernels.xent` and
+:mod:`repro.kernels.attention`: logit and score tiles never leave VMEM, and
+the backward recomputes them (attention from the saved ``lse``).
 """
 from __future__ import annotations
 
@@ -308,77 +297,199 @@ def _ref_norm(g: jnp.ndarray, kind: str, eps: float) -> jnp.ndarray:
     return _core_normalize(g, kind)
 
 
-def supported(shape, kind: str, mode: str | None = None) -> bool:
-    """True when (shape, kind) is covered by the fused kernels."""
-    if (resolve_mode() if mode is None else mode) == "off":
-        return False
+def supported(shape, kind: str) -> bool:
+    """True when (shape, kind) is covered by the fused update kernels."""
     if len(shape) not in FUSED_NDIMS or kind not in FUSED_KINDS:
         return False
     return all(d >= 1 for d in shape)
 
 
+def xent_supported(h_shape, w_shape, transposed: bool = False) -> bool:
+    """True when (h, w) shapes are covered by the fused xent kernels.
+
+    ``transposed``: w is a tied embedding stored (V, D) — the contraction
+    dim is then w's dim 1 instead of dim 0.
+    """
+    if len(h_shape) not in (2, 3) or len(w_shape) != 2:
+        return False
+    if h_shape[-1] != w_shape[1 if transposed else 0]:
+        return False
+    return all(d >= 1 for d in tuple(h_shape) + tuple(w_shape))
+
+
+def attn_supported(q_shape, kv_shape, causal: bool = True,
+                   v_shape=None) -> bool:
+    """True when (q, kv) shapes are covered by the fused attention kernels.
+
+    ``kv_shape`` is k's (B, T, K, hd); v (``v_shape``, when given) may
+    differ from k only in its last dim. Causal needs T >= S (the
+    rectangular offset T - S would otherwise put queries past the last
+    key).
+    """
+    if len(q_shape) != 4 or len(kv_shape) != 4:
+        return False
+    if v_shape is not None and tuple(v_shape[:3]) != tuple(kv_shape[:3]):
+        return False
+    B, S, H, hd = q_shape
+    if kv_shape[0] != B or kv_shape[3] != hd:
+        return False
+    K = kv_shape[2]
+    if K < 1 or H % K:
+        return False
+    if causal and kv_shape[1] < S:
+        return False
+    return all(d >= 1 for d in tuple(q_shape) + tuple(kv_shape))
+
+
 # --------------------------------------------------------------------------
-# Sharding plans
+# Shard plans: roles per operand dim (see the module docstring's table)
 # --------------------------------------------------------------------------
 
-class ShardPlan(NamedTuple):
-    """Static (hashable) shard_map recipe for one canonical (L, m, n) array.
+GATHERED = "gathered"
+CANON3 = ("stack", "rows", "cols")        # update operands, (L, m, n)
+QKV = ("batch", None, "heads", None)      # (B, S|T, H|K, hd)
+LSE = ("batch", "heads", None)            # attention lse, (B, H, S)
+SEG = ("batch", None)                     # segment ids, (B, S) / (B, T)
 
-    ``spec3[d]`` is the tuple of mesh axis names sharding canon3 dim ``d``.
+
+def _tok_roles(ndim: int) -> tuple:
+    """xent roles of h, labels and lse: tokens lead, the rest unsharded."""
+    return ("tok",) + (None,) * (ndim - 1)
+
+
+def _w_roles(transposed: bool) -> tuple:
+    return ("voc", GATHERED) if transposed else (GATHERED, "voc")
+
+
+# (sharding, shape, roles) per operand whose sharding the family is given
+def _update_operands(sharding, shape):
+    return ((sharding, shape, CANON3[-len(shape):]),)
+
+
+def _xent_operands(h_sharding, w_sharding, h_shape, w_shape, transposed):
+    return ((h_sharding, h_shape, _tok_roles(len(h_shape))),
+            (w_sharding, w_shape, _w_roles(transposed)))
+
+
+def _attn_operands(q_sharding, kv_sharding, q_shape, kv_shape):
+    return ((q_sharding, q_shape, QKV), (kv_sharding, kv_shape, QKV))
+
+
+def spec_mesh_axes(spec: P, ndim: int) -> tuple[tuple[str, ...], ...]:
+    """Canonicalize a PartitionSpec to per-dim tuples of mesh axis names.
+
+    Pads short specs with replicated dims, normalizes ``None`` -> ``()`` and
+    single names -> 1-tuples.
+    """
+    entries = tuple(spec) + (None,) * (ndim - len(tuple(spec)))
+    out = []
+    for e in entries[:ndim]:
+        if e is None:
+            out.append(())
+        elif isinstance(e, (tuple, list)):
+            out.append(tuple(e))
+        else:
+            out.append((e,))
+    return tuple(out)
+
+
+class Plan(NamedTuple):
+    """Static (hashable) shard_map recipe: the mesh, and the mesh axes of
+    each sharded role as (role, axes) pairs. A role left out is unsharded.
     """
     mesh: Mesh
-    spec3: tuple
+    axes: tuple
+
+    def of(self, role) -> tuple:
+        """Mesh axes sharding ``role``; () when it is not sharded."""
+        return dict(self.axes).get(role, ())
 
 
-def _plan_sharding(sharding, shape):
-    """-> None (single-device path) | "ref" (GSPMD jnp fallback) | ShardPlan.
+def _plan(operands):
+    """-> None (single-device path) | "ref" (GSPMD jnp fallback) | Plan.
 
-    "ref" is returned for shardings shard_map cannot express exactly
-    (non-NamedSharding layouts, dims not divisible by their mesh axes): the
-    jnp reference is partitioned correctly by GSPMD, whereas running the
-    kernels shard-locally would reduce over partial slices — the exact bug
-    this module exists to prevent.
+    ``operands``: one (sharding, shape, roles) triple per operand whose
+    sharding the caller knows (``None`` for an unknown one, read as
+    replicated), with one role per dim. The rules are the module
+    docstring's, in its order.
     """
-    if sharding is None:
+    if all(sh is None for sh, _, _ in operands):
         return None
-    if not isinstance(sharding, NamedSharding):
-        return "ref"
-    from repro.models.sharding import spec_mesh_axes
-    per_dim = spec_mesh_axes(sharding.spec, len(shape))
-    if len(shape) == 2:
-        per_dim = ((),) + per_dim
-    if all(not axs for axs in per_dim):
-        return None  # replicated: plain single-device semantics are exact
-    mesh = sharding.mesh
-    shape3 = (1,) + tuple(shape) if len(shape) == 2 else tuple(shape)
-    for dim, axs in zip(shape3, per_dim):
-        k = 1
-        for a in axs:
-            if a not in mesh.shape:
-                return "ref"
-            k *= mesh.shape[a]
-        if dim % k:
+    mesh = None
+    for sh, _, _ in operands:
+        if sh is None:
+            continue
+        if not isinstance(sh, NamedSharding) or (
+                mesh is not None and sh.mesh != mesh):
             return "ref"
-    return ShardPlan(mesh, per_dim)
+        mesh = sh.mesh
+    role_axes = {}
+    for sh, shape, roles in operands:
+        per = (spec_mesh_axes(sh.spec, len(shape)) if sh is not None
+               else ((),) * len(shape))
+        for dim, role, axs in zip(shape, roles, per):
+            if role == GATHERED:
+                continue
+            if role is None:
+                if axs:
+                    return "ref"
+                continue
+            if role_axes.setdefault(role, axs) != axs:
+                return "ref"
+            k = 1
+            for a in axs:
+                if a not in mesh.shape:
+                    return "ref"
+                k *= mesh.shape[a]
+            if dim % k:
+                return "ref"
+    used = [a for axs in role_axes.values() for a in axs]
+    if len(used) != len(set(used)):
+        return "ref"
+    if not used:
+        return None
+    return Plan(mesh, tuple((r, axs) for r, axs in role_axes.items() if axs))
 
 
-def _route(shape, kind, mode, sharding):
-    """-> ("ref", None) | ("kernel", None | ShardPlan)."""
-    if not supported(shape, kind, mode):
+def _shard(body, plan, in_roles, out_roles):
+    """``body`` under shard_map per ``plan``; ``body`` itself when None.
+
+    ``in_roles`` holds one roles tuple per argument (``()`` for a
+    replicated scalar); ``out_roles`` is one roles tuple, or a list of
+    them for a body that returns several arrays.
+    """
+    if plan is None:
+        return body
+
+    def spec(roles):
+        return P(*(plan.of(r) or None for r in roles))
+
+    out_specs = (tuple(map(spec, out_roles)) if isinstance(out_roles, list)
+                 else spec(out_roles))
+    return shard_map(body, mesh=plan.mesh,
+                     in_specs=tuple(map(spec, in_roles)),
+                     out_specs=out_specs, check_vma=False)
+
+
+def _route(mode, covered: bool, operands):
+    """-> ("ref", None) | ("kernel", None | Plan).
+
+    The one routing rule: ``REPRO_FUSED=off``, a shape outside the
+    coverage matrix, or a sharding the planner cannot express takes the
+    reference.
+    """
+    mode = resolve_mode() if mode is None else mode
+    if mode == "off" or not covered:
         return "ref", None
-    plan = _plan_sharding(sharding, shape)
+    plan = _plan(operands)
     if plan == "ref":
         return "ref", None
     return "kernel", plan
 
 
-def _pspec(spec3) -> P:
-    return P(*[axs if axs else None for axs in spec3])
-
-
-def _red_axes(plan: ShardPlan, axis: str):
+def _red_axes(plan: Plan, axis: str):
     """Mesh axes the per-slice sums-of-squares must psum over."""
-    return plan.spec3[1 if axis == "col" else 2]
+    return plan.of("rows" if axis == "col" else "cols")
 
 
 def _psum_ss(ss, plan, axis):
@@ -392,25 +503,6 @@ def _psum_ss(ss, plan, axis):
     # one update in an all-reduce of their own.
     with jax.named_scope("norm_psum"), set_xla_metadata(norm_psum="1"):
         return jax.lax.psum(ss, axes)
-
-
-def _mapped(body, plan, n_arrays, n_outs=1):
-    """Wrap ``body`` in shard_map per ``plan`` (identity when plan is None).
-
-    The first ``n_arrays`` args are (L, m, n) canon3 arrays sharded per
-    ``plan.spec3``; the rest are replicated scalars.
-    """
-    if plan is None:
-        return body
-    sp = _pspec(plan.spec3)
-
-    def wrapped(*args):
-        in_specs = (sp,) * n_arrays + (P(),) * (len(args) - n_arrays)
-        return shard_map(body, mesh=plan.mesh, in_specs=in_specs,
-                         out_specs=(sp,) * n_outs if n_outs > 1 else sp,
-                         check_vma=False)(*args)
-
-    return wrapped
 
 
 # --------------------------------------------------------------------------
@@ -506,9 +598,19 @@ def _guarded(op: str, kernel_thunk, ref_thunk):
         return _degrade(op, e, ref_thunk)
 
 
+def _run(op: str, mode, covered: bool, operands, kernel, ref):
+    """Every entry point's preamble: resolve REPRO_FUSED, route, then run
+    ``kernel(plan, mode)`` under the failure guard, or ``ref(mode)``."""
+    mode = resolve_mode() if mode is None else mode
+    route, plan = _route(mode, covered, operands)
+    if route == "ref":
+        return ref(mode)
+    return _guarded(op, lambda: kernel(plan, mode), lambda: ref(mode))
+
+
 # --------------------------------------------------------------------------
-# Entry points. Thin Python wrappers resolve REPRO_FUSED and the sharding
-# plan per call; the jitted impls take both as static args (cache-keyed).
+# Optimizer updates. The jitted impls take the resolved mode and the plan
+# (or "ref") as static args, so both key their caches.
 # --------------------------------------------------------------------------
 
 def _gs_arg(gscale):
@@ -519,6 +621,16 @@ def _gs_arg(gscale):
 def _scaled_ref(g, gs, has_gs):
     # mirrors the trainer's clip tree-map (g * scale in g's promoted dtype)
     return g * gs if has_gs else g
+
+
+def _update(op: str, impl, shape, kind, sharding, mode, *args, **kw):
+    """Route one update op: ``impl(*args)`` with its kernel plan or "ref"."""
+    def call(plan, mode):
+        return impl(*args, plan=plan, mode=mode, kind=kind, **kw)
+
+    return _run(op, mode, supported(shape, kind),
+                _update_operands(sharding, shape), call,
+                lambda mode: call("ref", mode))
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "eps", "mode", "plan",
@@ -536,21 +648,16 @@ def _normalize_impl(g, gs, *, kind, eps, mode, plan, has_gs):
         return _ck.norm_apply(g3, ss, axis, eps=eps, interpret=interp,
                               gscale=gs)
 
-    return _mapped(body, plan, 1)(_c3(g), gs).reshape(g.shape)
+    return _shard(body, plan, (CANON3, ()), CANON3)(
+        _c3(g), gs).reshape(g.shape)
 
 
 def normalize(g: jnp.ndarray, kind: str = "col", eps: float = 1e-8, *,
               gscale=None, sharding=None, mode: str | None = None):
     """Fused gscale*g / (||slice||+eps); jnp oracle off-matrix."""
-    mode = resolve_mode() if mode is None else mode
-    route, plan = _route(g.shape, kind, mode, sharding)
     has_gs, gs = _gs_arg(gscale)
-    kw = dict(kind=kind, eps=eps, mode=mode, has_gs=has_gs)
-    if route == "kernel":
-        return _guarded("normalize",
-                        lambda: _normalize_impl(g, gs, plan=plan, **kw),
-                        lambda: _normalize_impl(g, gs, plan="ref", **kw))
-    return _normalize_impl(g, gs, plan="ref", **kw)
+    return _update("normalize", _normalize_impl, g.shape, kind, sharding,
+                   mode, g, gs, eps=eps, has_gs=has_gs)
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "eps", "mode", "plan",
@@ -573,24 +680,17 @@ def _norm_update_impl(theta, g, lr, gs, *, kind, eps, mode, plan, has_gs):
                                 interpret=interp, gscale=gs)
 
     lr = jnp.asarray(lr, jnp.float32)
-    return _mapped(body, plan, 2)(_c3(theta), _c3(g), gs,
-                                  lr).reshape(theta.shape)
+    return _shard(body, plan, (CANON3, CANON3, (), ()), CANON3)(
+        _c3(theta), _c3(g), gs, lr).reshape(theta.shape)
 
 
 def norm_update(theta: jnp.ndarray, g: jnp.ndarray, lr, kind: str = "col",
                 eps: float = 1e-8, *, gscale=None, sharding=None,
                 mode: str | None = None):
     """Fused theta - lr*normalize(gscale*g); 3-pass per-shard apply stage."""
-    mode = resolve_mode() if mode is None else mode
-    route, plan = _route(theta.shape, kind, mode, sharding)
     has_gs, gs = _gs_arg(gscale)
-    kw = dict(kind=kind, eps=eps, mode=mode, has_gs=has_gs)
-    if route == "kernel":
-        return _guarded(
-            "norm_update",
-            lambda: _norm_update_impl(theta, g, lr, gs, plan=plan, **kw),
-            lambda: _norm_update_impl(theta, g, lr, gs, plan="ref", **kw))
-    return _norm_update_impl(theta, g, lr, gs, plan="ref", **kw)
+    return _update("norm_update", _norm_update_impl, theta.shape, kind,
+                   sharding, mode, theta, g, lr, gs, eps=eps, has_gs=has_gs)
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "eps", "mode", "plan",
@@ -623,7 +723,8 @@ def _momentum_norm_impl(m, g, beta, gs, *, kind, eps, mode, plan, has_gs):
         return m_new, d
 
     beta = jnp.asarray(beta, jnp.float32)
-    m_new, d = _mapped(body, plan, 2, n_outs=2)(_c3(m), _c3(g), gs, beta)
+    m_new, d = _shard(body, plan, (CANON3, CANON3, (), ()),
+                      [CANON3, CANON3])(_c3(m), _c3(g), gs, beta)
     return m_new.reshape(m.shape), d.reshape(m.shape)
 
 
@@ -631,16 +732,9 @@ def momentum_norm(m: jnp.ndarray, g: jnp.ndarray, beta, kind: str = "col",
                   eps: float = 1e-8, *, gscale=None, sharding=None,
                   mode: str | None = None):
     """(m', normalize(m')) with the EMA and sumsq fused into one kernel."""
-    mode = resolve_mode() if mode is None else mode
-    route, plan = _route(m.shape, kind, mode, sharding)
     has_gs, gs = _gs_arg(gscale)
-    kw = dict(kind=kind, eps=eps, mode=mode, has_gs=has_gs)
-    if route == "kernel":
-        return _guarded(
-            "momentum_norm",
-            lambda: _momentum_norm_impl(m, g, beta, gs, plan=plan, **kw),
-            lambda: _momentum_norm_impl(m, g, beta, gs, plan="ref", **kw))
-    return _momentum_norm_impl(m, g, beta, gs, plan="ref", **kw)
+    return _update("momentum_norm", _momentum_norm_impl, m.shape, kind,
+                   sharding, mode, m, g, beta, gs, eps=eps, has_gs=has_gs)
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "eps", "mode", "plan",
@@ -668,7 +762,8 @@ def _momentum_norm_update_impl(theta, m, g, beta, lr, gs, *, kind, eps, mode,
 
     beta = jnp.asarray(beta, jnp.float32)
     lr = jnp.asarray(lr, jnp.float32)
-    t_new, m_new = _mapped(body, plan, 3, n_outs=2)(
+    t_new, m_new = _shard(body, plan, (CANON3, CANON3, CANON3, (), (), ()),
+                          [CANON3, CANON3])(
         _c3(theta), _c3(m), _c3(g), gs, beta, lr)
     return t_new.reshape(theta.shape), m_new.reshape(m.shape)
 
@@ -677,124 +772,28 @@ def momentum_norm_update(theta: jnp.ndarray, m: jnp.ndarray, g: jnp.ndarray,
                          beta, lr, kind: str = "col", eps: float = 1e-8, *,
                          gscale=None, sharding=None, mode: str | None = None):
     """Fully fused stateful step: (theta', m') in two kernel launches."""
-    mode = resolve_mode() if mode is None else mode
-    route, plan = _route(theta.shape, kind, mode, sharding)
     has_gs, gs = _gs_arg(gscale)
-    kw = dict(kind=kind, eps=eps, mode=mode, has_gs=has_gs)
-    if route == "kernel":
-        return _guarded(
-            "momentum_norm_update",
-            lambda: _momentum_norm_update_impl(theta, m, g, beta, lr, gs,
-                                               plan=plan, **kw),
-            lambda: _momentum_norm_update_impl(theta, m, g, beta, lr, gs,
-                                               plan="ref", **kw))
-    return _momentum_norm_update_impl(theta, m, g, beta, lr, gs, plan="ref",
-                                      **kw)
+    return _update("momentum_norm_update", _momentum_norm_update_impl,
+                   theta.shape, kind, sharding, mode, theta, m, g, beta, lr,
+                   gs, eps=eps, has_gs=has_gs)
 
 
 # --------------------------------------------------------------------------
 # Fused LM-head cross-entropy
 # --------------------------------------------------------------------------
 
-class XentPlan(NamedTuple):
-    """Static shard_map recipe for the fused xent.
-
-    ``tok_axes``: mesh axes sharding the leading (batch) dim of h/labels.
-    ``voc_axes``: mesh axes sharding w's vocab dim (dim 1; dim 0 for a
-    transposed/tied w). w's embed dim is always gathered inside the
-    shard_map (in_spec ``None``).
-    """
-    mesh: Mesh
-    tok_axes: tuple
-    voc_axes: tuple
-
-
-def xent_supported(h_shape, w_shape, mode: str | None = None,
-                   transposed: bool = False) -> bool:
-    """True when (h, w) shapes are covered by the fused xent kernels.
-
-    ``transposed``: w is a tied embedding stored (V, D) — the contraction
-    dim is then w's dim 1 instead of dim 0.
-    """
-    if (resolve_mode() if mode is None else mode) == "off":
-        return False
-    if len(h_shape) not in (2, 3) or len(w_shape) != 2:
-        return False
-    if h_shape[-1] != w_shape[1 if transposed else 0]:
-        return False
-    return all(d >= 1 for d in tuple(h_shape) + tuple(w_shape))
-
-
-def _axes_prod(mesh: Mesh, axes) -> int | None:
-    k = 1
-    for a in axes:
-        if a not in mesh.shape:
-            return None
-        k *= mesh.shape[a]
-    return k
-
-
-def _plan_xent(h_sharding, w_sharding, h_shape, w_shape,
-               transposed: bool = False):
-    """-> None (single-device) | "ref" | XentPlan.
-
-    "ref" for layouts shard_map cannot express exactly: non-NamedSharding,
-    mismatched meshes, h sharded on a non-leading dim (seq/embed), or
-    token/vocab dims not divisible by their mesh axes. The jnp chunked
-    path partitions those correctly through GSPMD. For a transposed (tied)
-    w the vocab dim is w's dim 0 and the gathered embed dim is dim 1.
-    """
-    if h_sharding is None and w_sharding is None:
-        return None
-    mesh = None
-    for sh in (h_sharding, w_sharding):
-        if sh is None:
-            continue
-        if not isinstance(sh, NamedSharding):
-            return "ref"
-        if mesh is not None and sh.mesh != mesh:
-            return "ref"
-        mesh = sh.mesh
-    from repro.models.sharding import spec_mesh_axes
-    voc_dim = 0 if transposed else 1
-    tok_axes = voc_axes = ()
-    if h_sharding is not None:
-        per = spec_mesh_axes(h_sharding.spec, len(h_shape))
-        if any(per[1:]):
-            return "ref"  # seq- or embed-sharded hidden: GSPMD handles it
-        tok_axes = per[0]
-    if w_sharding is not None:
-        voc_axes = spec_mesh_axes(w_sharding.spec, 2)[voc_dim]
-    if not tok_axes and not voc_axes:
-        return None  # replicated (or only w's gathered embed dim sharded)
-    if set(tok_axes) & set(voc_axes):
-        # one mesh axis sharding both tokens and vocab: each device holds
-        # a *different* token block AND vocab block, so the lse/ll psum
-        # over that axis would mix statistics across token shards —
-        # silently wrong, exactly what the ref fallback exists to prevent
-        return "ref"
-    kt = _axes_prod(mesh, tok_axes)
-    kv = _axes_prod(mesh, voc_axes)
-    if kt is None or kv is None or h_shape[0] % kt or w_shape[voc_dim] % kv:
-        return "ref"
-    return XentPlan(mesh, tuple(tok_axes), tuple(voc_axes))
-
-
 def xent_route(h_shape, w_shape, mode: str | None = None, h_sharding=None,
                w_sharding=None, transposed: bool = False):
-    """-> ("ref", None) | ("kernel", None | XentPlan).
+    """-> ("ref", None) | ("kernel", None | Plan).
 
     Callers that must never materialize full logits (the model's loss)
     take their own chunked path on "ref"; ``xent_loss``'s built-in ref is
     the full-logit test-scale oracle. ``transposed``: w is the tied (V, D)
     embedding (see the coverage matrix).
     """
-    if not xent_supported(h_shape, w_shape, mode, transposed):
-        return "ref", None
-    plan = _plan_xent(h_sharding, w_sharding, h_shape, w_shape, transposed)
-    if plan == "ref":
-        return "ref", None
-    return "kernel", plan
+    return _route(mode, xent_supported(h_shape, w_shape, transposed),
+                  _xent_operands(h_sharding, w_sharding, h_shape, w_shape,
+                                 transposed))
 
 
 @functools.lru_cache(maxsize=None)
@@ -803,13 +802,13 @@ def _xent_fused(vocab_size: int, interp: bool, plan, block,
     """Build the custom_vjp'd fused xent for one static configuration.
 
     Cached so repeated traces reuse one custom_vjp object (and its jit
-    caches). ``plan`` is an XentPlan or None; ``block`` a (bn, bv) tuple
-    or None; ``transposed`` selects the tied (V, D) w layout — dW then
-    comes back in (V, D), landing directly on the embedding cotangent.
+    caches). ``plan`` is a Plan or None; ``block`` a (bn, bv) tuple or
+    None; ``transposed`` selects the tied (V, D) w layout — dW then comes
+    back in (V, D), landing directly on the embedding cotangent.
     """
-    mesh = plan.mesh if plan is not None else None
-    tok_axes = plan.tok_axes if plan is not None else ()
-    voc_axes = plan.voc_axes if plan is not None else ()
+    tok_axes = plan.of("tok") if plan is not None else ()
+    voc_axes = plan.of("voc") if plan is not None else ()
+    w_roles = _w_roles(transposed)
     _v_local = (lambda wb: wb.shape[0]) if transposed \
         else (lambda wb: wb.shape[1])
 
@@ -819,16 +818,8 @@ def _xent_fused(vocab_size: int, interp: bool, plan, block,
             return 0
         idx = jnp.int32(0)
         for a in voc_axes:  # major-to-minor, matching GSPMD's dim split
-            idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
+            idx = idx * plan.mesh.shape[a] + jax.lax.axis_index(a)
         return idx * v_local
-
-    def _specs(h_ndim, lab_ndim):
-        tok = tuple(tok_axes) or None
-        hspec = P(*(tok,) + (None,) * (h_ndim - 1))
-        lspec = P(*(tok,) + (None,) * (lab_ndim - 1))
-        voc = tuple(voc_axes) or None
-        wspec = P(voc, None) if transposed else P(None, voc)
-        return hspec, wspec, lspec
 
     def _fwd_parts(h, w, labels):
         def body(hb, wb, lab):
@@ -842,12 +833,9 @@ def _xent_fused(vocab_size: int, interp: bool, plan, block,
                 ll = jax.lax.psum(ll, voc_axes)
             return lse.reshape(lab.shape), ll.reshape(lab.shape)
 
-        if plan is None:
-            return body(h, w, labels)
-        hspec, wspec, lspec = _specs(h.ndim, labels.ndim)
-        return shard_map(body, mesh=mesh, in_specs=(hspec, wspec, lspec),
-                         out_specs=(lspec, lspec), check_vma=False)(
-                             h, w, labels)
+        hr, lab = _tok_roles(h.ndim), _tok_roles(labels.ndim)
+        return _shard(body, plan, (hr, w_roles, lab), [lab, lab])(
+            h, w, labels)
 
     def _bwd_parts(h, w, labels, lse, gl):
         def body(hb, wb, lab, lse_, gl_):
@@ -872,13 +860,9 @@ def _xent_fused(vocab_size: int, interp: bool, plan, block,
                 dh, dw = jax.lax.optimization_barrier((dh, dw))
             return dh.reshape(hb.shape), dw
 
-        if plan is None:
-            return body(h, w, labels, lse, gl)
-        hspec, wspec, lspec = _specs(h.ndim, labels.ndim)
-        return shard_map(body, mesh=mesh,
-                         in_specs=(hspec, wspec, lspec, lspec, lspec),
-                         out_specs=(hspec, wspec), check_vma=False)(
-                             h, w, labels, lse, gl)
+        hr, lab = _tok_roles(h.ndim), _tok_roles(labels.ndim)
+        return _shard(body, plan, (hr, w_roles, lab, lab, lab),
+                      [hr, w_roles])(h, w, labels, lse, gl)
 
     @jax.custom_vjp
     def fused(h, w, labels):
@@ -930,20 +914,15 @@ def xent_loss(h: jnp.ndarray, w: jnp.ndarray, labels: jnp.ndarray, *,
     """
     if weights is not None:
         labels = jnp.where(weights > 0, labels, -1)
-    mode = resolve_mode() if mode is None else mode
-    route, plan = xent_route(h.shape, w.shape, mode, h_sharding, w_sharding,
-                             transposed)
-    if route == "ref":
-        losses = _xent_ref(h, w, labels, vocab_size=vocab_size,
-                           transposed=transposed)
-    else:
-        losses = _guarded(
-            "xent_loss",
-            lambda: _xent_fused(vocab_size, use_interpret(mode), plan,
-                                tuple(block) if block is not None else None,
-                                transposed)(h, w, labels),
-            lambda: _xent_ref(h, w, labels, vocab_size=vocab_size,
-                              transposed=transposed))
+    losses = _run(
+        "xent_loss", mode, xent_supported(h.shape, w.shape, transposed),
+        _xent_operands(h_sharding, w_sharding, h.shape, w.shape, transposed),
+        lambda plan, mode: _xent_fused(
+            vocab_size, use_interpret(mode), plan,
+            tuple(block) if block is not None else None, transposed)(
+                h, w, labels),
+        lambda mode: _xent_ref(h, w, labels, vocab_size=vocab_size,
+                               transposed=transposed))
     if weights is not None:
         losses = losses * weights.astype(losses.dtype)
     return losses
@@ -953,99 +932,17 @@ def xent_loss(h: jnp.ndarray, w: jnp.ndarray, labels: jnp.ndarray, *,
 # Fused flash attention
 # --------------------------------------------------------------------------
 
-class AttnPlan(NamedTuple):
-    """Static shard_map recipe for the fused attention.
-
-    ``batch_axes``: mesh axes sharding the leading (batch) dim of q *and*
-    kv. ``head_axes``: mesh axes sharding q's H and kv's K head dims (both
-    must divide, so the GQA group ratio is preserved per shard). There are
-    no cross-shard reductions: every (batch, head) pair is device-local.
-    """
-    mesh: Mesh
-    batch_axes: tuple
-    head_axes: tuple
-
-
-def attn_supported(q_shape, kv_shape, causal: bool = True,
-                   mode: str | None = None) -> bool:
-    """True when (q, kv) shapes are covered by the fused attention kernels.
-
-    ``kv_shape`` is k's (B, T, K, hd); v may differ only in its last dim.
-    Causal needs T >= S (the rectangular offset T - S would otherwise put
-    queries past the last key).
-    """
-    if (resolve_mode() if mode is None else mode) == "off":
-        return False
-    if len(q_shape) != 4 or len(kv_shape) != 4:
-        return False
-    B, S, H, hd = q_shape
-    if kv_shape[0] != B or kv_shape[3] != hd:
-        return False
-    K = kv_shape[2]
-    if K < 1 or H % K:
-        return False
-    if causal and kv_shape[1] < S:
-        return False
-    return all(d >= 1 for d in tuple(q_shape) + tuple(kv_shape))
-
-
-def _plan_attn(q_sharding, kv_sharding, q_shape, kv_shape):
-    """-> None (single-device) | "ref" | AttnPlan.
-
-    "ref" for layouts the batch/head shard_map cannot express exactly:
-    non-NamedSharding, mismatched meshes, sequence- or head_dim-sharded
-    operands (the seq-sharded decode cache), batch/head axes that differ
-    between q and kv (e.g. MQA kv left replicated by the divisibility
-    guard while q heads are TP-sharded — the kernel's ``q_head // group``
-    indexing assumes aligned shards), or dims not divisible by their mesh
-    axes. The jnp scan partitions those correctly through GSPMD.
-    """
-    if q_sharding is None and kv_sharding is None:
-        return None
-    mesh = None
-    for sh in (q_sharding, kv_sharding):
-        if sh is None:
-            continue
-        if not isinstance(sh, NamedSharding):
-            return "ref"
-        if mesh is not None and sh.mesh != mesh:
-            return "ref"
-        mesh = sh.mesh
-    from repro.models.sharding import spec_mesh_axes
-    qper = spec_mesh_axes(q_sharding.spec, 4) if q_sharding is not None \
-        else ((),) * 4
-    kper = spec_mesh_axes(kv_sharding.spec, 4) if kv_sharding is not None \
-        else ((),) * 4
-    if any(qper[1]) or any(qper[3]) or any(kper[1]) or any(kper[3]):
-        return "ref"  # seq- or head_dim-sharded: GSPMD handles it
-    if qper[0] != kper[0] or qper[2] != kper[2]:
-        return "ref"  # q and kv must shard batch/heads identically
-    batch_axes, head_axes = tuple(qper[0]), tuple(qper[2])
-    if not batch_axes and not head_axes:
-        return None  # replicated: plain single-device semantics are exact
-    kb = _axes_prod(mesh, batch_axes)
-    kh = _axes_prod(mesh, head_axes)
-    if kb is None or kh is None:
-        return "ref"
-    if q_shape[0] % kb or q_shape[2] % kh or kv_shape[2] % kh:
-        return "ref"
-    return AttnPlan(mesh, batch_axes, head_axes)
-
-
 def attn_route(q_shape, kv_shape, causal: bool = True,
-               mode: str | None = None, q_sharding=None, kv_sharding=None):
-    """-> ("ref", None) | ("kernel", None | AttnPlan).
+               mode: str | None = None, q_sharding=None, kv_sharding=None,
+               v_shape=None):
+    """-> ("ref", None) | ("kernel", None | Plan).
 
     Callers with their own memory-safe jnp path (``models.layers``) take
     it on "ref"; ``flash_attention``'s built-in ref delegates back to the
     layer-level scan/chunked implementations.
     """
-    if not attn_supported(q_shape, kv_shape, causal, mode):
-        return "ref", None
-    plan = _plan_attn(q_sharding, kv_sharding, q_shape, kv_shape)
-    if plan == "ref":
-        return "ref", None
-    return "kernel", plan
+    return _route(mode, attn_supported(q_shape, kv_shape, causal, v_shape),
+                  _attn_operands(q_sharding, kv_sharding, q_shape, kv_shape))
 
 
 def _check_kv_len(causal: bool, kv_len):
@@ -1091,21 +988,13 @@ def _attn_fused(scale: float, spec: MaskSpec, interp: bool, plan, block):
     """Build the custom_vjp'd fused attention for one static configuration.
 
     Cached so repeated traces reuse one custom_vjp object. ``spec`` is the
-    (hashable) :class:`MaskSpec`; ``plan`` an AttnPlan or None; ``block``
-    a (bq, bk) tuple or None. The traced mask operands — the ``kv_len``
+    (hashable) :class:`MaskSpec`; ``plan`` a Plan or None; ``block`` a
+    (bq, bk) tuple or None. The traced mask operands — the ``kv_len``
     scalar and the (B, S)/(B, T) segment ids — ride along as custom_vjp
     arguments with float0 cotangents (index data, like xent's labels);
     when the spec declares no segments the pair is a zero-size dummy the
     kernels never read.
     """
-    mesh = plan.mesh if plan is not None else None
-    if plan is not None:
-        bt = tuple(plan.batch_axes) or None
-        hx = tuple(plan.head_axes) or None
-        qspec = P(bt, None, hx, None)   # (B, S|T, H|K, hd) operand layout
-        lspec = P(bt, hx, None)         # (B, H, S) lse layout
-        sspec = P(bt, None)             # (B, S)/(B, T) segment-id layout
-
     def _segs(qs, ks):
         return (qs, ks) if spec.has_segments else None
 
@@ -1115,12 +1004,8 @@ def _attn_fused(scale: float, spec: MaskSpec, interp: bool, plan, block):
                                segments=_segs(qsb, ksb), block=block,
                                interpret=interp)
 
-        if plan is None:
-            return body(q, k, v, kl, qs, ks)
-        return shard_map(body, mesh=mesh,
-                         in_specs=(qspec, qspec, qspec, P(), sspec, sspec),
-                         out_specs=(qspec, lspec), check_vma=False)(
-                             q, k, v, kl, qs, ks)
+        return _shard(body, plan, (QKV, QKV, QKV, (), SEG, SEG),
+                      [QKV, LSE])(q, k, v, kl, qs, ks)
 
     def _bwd_parts(q, k, v, kl, qs, ks, out, lse, do):
         def body(qb, kb, vb, kl_, qsb, ksb, ob, lseb, dob):
@@ -1136,13 +1021,9 @@ def _attn_fused(scale: float, spec: MaskSpec, interp: bool, plan, block):
                                      block=block, interpret=interp)
             return dq, dk, dv
 
-        if plan is None:
-            return body(q, k, v, kl, qs, ks, out, lse, do)
-        return shard_map(body, mesh=mesh,
-                         in_specs=(qspec, qspec, qspec, P(), sspec, sspec,
-                                   qspec, lspec, qspec),
-                         out_specs=(qspec, qspec, qspec),
-                         check_vma=False)(q, k, v, kl, qs, ks, out, lse, do)
+        return _shard(body, plan,
+                      (QKV, QKV, QKV, (), SEG, SEG, QKV, LSE, QKV),
+                      [QKV, QKV, QKV])(q, k, v, kl, qs, ks, out, lse, do)
 
     @jax.custom_vjp
     def fused(q, k, v, kl, qs, ks):
@@ -1180,28 +1061,27 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     combination) and mutually exclusive with ``segments`` (packed batches
     have no cache-fill bound).
     """
-    mode = resolve_mode() if mode is None else mode
     _check_kv_len(causal, kv_len)
     spec = mask_spec(q.shape[1], k.shape[1], causal=causal, kv_len=kv_len,
                      segments=segments)
-    route, plan = attn_route(q.shape, k.shape, causal, mode, q_sharding,
-                             kv_sharding)
-    if route == "ref" or v.shape[:3] != k.shape[:3]:
-        return _attn_ref(q, k, v, scale=scale, causal=causal, kv_len=kv_len,
-                         segments=segments)
-    kl = jnp.asarray(k.shape[1] if kv_len is None else kv_len, jnp.int32)
-    if segments is not None:
-        qs = segments[0].astype(jnp.int32)
-        ks = segments[1].astype(jnp.int32)
-    else:  # fixed custom_vjp arity: zero-size stand-ins, never read
-        qs = ks = jnp.zeros((q.shape[0], 0), jnp.int32)
-    return _guarded(
-        "flash_attention",
-        lambda: _attn_fused(float(scale), spec, use_interpret(mode), plan,
-                            tuple(block) if block is not None else None)(
-                                q, k, v, kl, qs, ks),
-        lambda: _attn_ref(q, k, v, scale=scale, causal=causal,
-                          kv_len=kv_len, segments=segments))
+
+    def kernel(plan, mode):
+        kl = jnp.asarray(k.shape[1] if kv_len is None else kv_len, jnp.int32)
+        if segments is not None:
+            qs = segments[0].astype(jnp.int32)
+            ks = segments[1].astype(jnp.int32)
+        else:  # fixed custom_vjp arity: zero-size stand-ins, never read
+            qs = ks = jnp.zeros((q.shape[0], 0), jnp.int32)
+        return _attn_fused(float(scale), spec, use_interpret(mode), plan,
+                           tuple(block) if block is not None else None)(
+                               q, k, v, kl, qs, ks)
+
+    return _run(
+        "flash_attention", mode,
+        attn_supported(q.shape, k.shape, causal, v.shape),
+        _attn_operands(q_sharding, kv_sharding, q.shape, k.shape), kernel,
+        lambda mode: _attn_ref(q, k, v, scale=scale, causal=causal,
+                               kv_len=kv_len, segments=segments))
 
 
 # Introspection: op name -> (fused entry point, jnp reference). Tests iterate

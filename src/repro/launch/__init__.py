@@ -1,1 +1,1 @@
-"""repro.launch — production meshes, dry-run, roofline, training CLI."""
+"""repro.launch — meshes, the training CLI, compiled-step introspection."""

@@ -2,8 +2,8 @@
 
 Runs a real training loop on the devices JAX finds — one TPU chip or a
 four-chip host, or the CPU, where the tests run it with the Pallas kernels
-interpreted — with parameters initialised sharded by the same rules table
-the dry-run uses, each batch placed along the mesh's batch axes,
+interpreted — with parameters initialised sharded by the model's rules
+table, each batch placed along the mesh's batch axes,
 deterministic resumable data, periodic checkpointing and auto-resume.
 
 Compile cache: on a TPU, ``main`` keeps JAX's persistent compilation cache
@@ -107,9 +107,8 @@ def build(args, lr_scale: float = 1.0):
 def make_tx(cfg, optimizer: str, lr):
     """``make_optimizer(optimizer, lr)`` for ``cfg``'s parameter tree."""
     if cfg.tie_embeddings:
-        # feature-detect rather than enumerate names (like the trainer's
-        # shardings/grad_scale detection): any optimizer whose factory
-        # takes LabelRules gets the tied embedding routed to the 'last'
+        # feature-detect rather than enumerate names: any optimizer whose
+        # factory takes LabelRules gets the tied embedding routed to the 'last'
         # group — scale would otherwise hard-error (a tied tree has no
         # lm_head to carry the momentum). Optimizers without a rules
         # kwarg treat every matrix alike, so there is nothing to route;

@@ -366,10 +366,10 @@ def _route_attention(q, k, v, scale: float, *, causal: bool, kv_len=None,
                      kv_axes=("act_batch", None, "act_heads", None)):
     """Fused-kernel route for one attention call (None -> caller's jnp path).
 
-    Mirrors ``model.lm_loss``'s xent routing: resolve REPRO_FUSED once,
-    derive the q/kv NamedShardings from the logical rules when a mesh is
-    given (``kv_axes`` lets the decode path describe its cache layout),
-    and only call the dispatch entry point when it will actually run the
+    Mirrors ``model.lm_loss``'s xent routing: derive the q/kv
+    NamedShardings from the logical rules when a mesh is given
+    (``kv_axes`` lets the decode path describe its cache layout), and
+    only call the dispatch entry point when it will actually run the
     kernels — the callers keep their own scan/chunked reference paths.
     """
     from repro.kernels import dispatch as _kd  # lazy: optional kernel layer
@@ -378,13 +378,12 @@ def _route_attention(q, k, v, scale: float, *, causal: bool, kv_len=None,
         q_sh = rules.sharding(("act_batch", None, "act_heads", None), mesh,
                               q.shape)
         kv_sh = rules.sharding(kv_axes, mesh, k.shape)
-    mode = _kd.resolve_mode()
-    route, _ = _kd.attn_route(q.shape, k.shape, causal, mode, q_sh, kv_sh)
-    if route != "kernel" or v.shape[:3] != k.shape[:3]:
+    if _kd.attn_route(q.shape, k.shape, causal, None, q_sh, kv_sh,
+                      v.shape)[0] != "kernel":
         return None
     return _kd.flash_attention(q, k, v, scale=scale, causal=causal,
                                kv_len=kv_len, segments=segments,
-                               q_sharding=q_sh, kv_sharding=kv_sh, mode=mode)
+                               q_sharding=q_sh, kv_sharding=kv_sh)
 
 
 def causal_blockwise_attention(q, k, v, block: int, scale: float, *,
@@ -613,7 +612,7 @@ def apply_mla_attention(p, cfg: ModelConfig, x, positions, rules: Rules,
     """Multi-head Latent Attention (DeepSeek-V2/V3).
 
     Caches only the compressed kv latent (kv_lora_rank) + shared rope key —
-    the architecture's memory win, visible directly in the dry-run bytes.
+    the architecture's memory win.
     """
     B, S, D = x.shape
     H = cfg.n_heads

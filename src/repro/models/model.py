@@ -291,10 +291,9 @@ def lm_loss(params, cfg: ModelConfig, hidden, labels,
     * **fused** (default where covered): the Pallas blockwise kernels
       behind ``dispatch.xent_loss`` — logits live only as a
       (token-tile, vocab-tile) VMEM block, the backward emits dH/dW from
-      the same tiles (custom_vjp). ``mesh`` (passed by the trainer, which
-      feature-detects this kwarg) lets the dispatch shard_map the kernels
-      using the head's ("embed", "vocab") and the activations'
-      ("act_batch", ...) logical axes.
+      the same tiles (custom_vjp). ``mesh`` (passed by the trainer) lets
+      the dispatch shard_map the kernels using the head's ("embed",
+      "vocab") and the activations' ("act_batch", ...) logical axes.
     * **chunked jnp scan** (``REPRO_FUSED=off`` or uncovered
       shape/sharding): the original reference path — (chunk, V) f32
       logit blocks per scan step, bitwise-stable across PRs.
@@ -330,12 +329,8 @@ def lm_loss(params, cfg: ModelConfig, hidden, labels,
                               hidden.shape)
         w_sh = rules.sharding(("vocab", "embed") if tied
                               else ("embed", "vocab"), mesh, head_shape)
-    # resolve REPRO_FUSED once and thread it through: the branch taken
-    # here and the route inside xent_loss must come from the same read
-    mode = _kd.resolve_mode()
-    route, _ = _kd.xent_route(hidden.shape, head_shape, mode,
-                              h_sharding=h_sh, w_sharding=w_sh,
-                              transposed=tied)
+    route, _ = _kd.xent_route(hidden.shape, head_shape, h_sharding=h_sh,
+                              w_sharding=w_sh, transposed=tied)
     # mean = sum / effective weight; a zero effective weight (all tokens
     # masked) yields loss 0 via a neutral denominator — NOT max(ws, 1),
     # which silently deflated fractional-weight sums in (0, 1)
@@ -344,7 +339,7 @@ def lm_loss(params, cfg: ModelConfig, hidden, labels,
     if route == "kernel":
         def head_loss_sums(wh, labs):
             losses = _kd.xent_loss(hidden, wh, labs,
-                                   vocab_size=cfg.vocab_size, mode=mode,
+                                   vocab_size=cfg.vocab_size,
                                    weights=weights,
                                    h_sharding=h_sh, w_sharding=w_sh,
                                    transposed=tied)

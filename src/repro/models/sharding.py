@@ -118,26 +118,6 @@ class Rules:
         return NamedSharding(mesh, self.spec(logical_axes, mesh, shape))
 
 
-def spec_mesh_axes(spec: P, ndim: int) -> Tuple[Tuple[str, ...], ...]:
-    """Canonicalize a PartitionSpec to per-dim tuples of mesh axis names.
-
-    Pads short specs with replicated dims, normalizes ``None`` -> ``()`` and
-    single names -> 1-tuples. This is the form the fused-update dispatch
-    consumes to decide which mesh axes a col/row norm must ``psum`` over
-    (the axes sharding the reduce dim of the matrix).
-    """
-    entries = tuple(spec) + (None,) * (ndim - len(tuple(spec)))
-    out = []
-    for e in entries[:ndim]:
-        if e is None:
-            out.append(())
-        elif isinstance(e, (tuple, list)):
-            out.append(tuple(e))
-        else:
-            out.append((e,))
-    return tuple(out)
-
-
 def shard(x, logical_axes, rules: Rules, mesh: Optional[Mesh] = None):
     """Annotate an activation with its logical sharding (no-op off-mesh).
 

@@ -177,7 +177,8 @@ def apply_superblock(kind: str, cfg: ModelConfig, params: dict, x, positions,
         if new_cache is not None and _needs_cache(sub) and sub_cache is not None:
             new_cache[name] = sub_cache
         aux_total = aux_total + aux
-    # scan-carry sharding: lets the dry-run store saved residuals TP-sharded
+    # scan-carry sharding: an act_residual rule stores saved residuals
+    # TP-sharded
     x = shard(x, ("act_batch", "act_seq", "act_residual"), rules)
     return x, new_cache, aux_total
 

@@ -1,6 +1,5 @@
 """Serving-step factories: batched prefill + single-token decode with a
-persistent sharded KV/SSM cache. These are the functions the inference
-dry-run cells lower (``prefill_32k`` / ``decode_32k`` / ``long_500k``).
+persistent sharded KV/SSM cache.
 """
 from __future__ import annotations
 

@@ -5,7 +5,6 @@ function suitable for pjit sharding. An optional in-jit anomaly guard
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any, NamedTuple, Optional
 
 import jax
@@ -61,8 +60,7 @@ def make_train_step(cfg, tx: GradientTransformation, grad_accum: int = 1,
     standard large-scale recipe); per-microbatch auxiliary metrics (MoE
     aux-loss, token weight) are averaged alongside the loss. ``accum_dtype``
     controls the accumulator precision — f32 by default; bf16 halves the
-    accumulator HBM footprint for the largest models (dry-run default for
-    >300B params).
+    accumulator HBM footprint for the largest models.
 
     ``fused_apply`` selects the optimizer's fused parameter write
     (``tx.update_params``: theta is read and written once, no materialized
@@ -79,16 +77,15 @@ def make_train_step(cfg, tx: GradientTransformation, grad_accum: int = 1,
     kernels: the per-parameter ``NamedSharding`` tree (from ``rules`` +
     the model's logical axes) is passed to ``tx.update_params`` so the
     fused kernels shard_map over the mesh and psum their norm reductions.
-    Optimizers without a ``shardings`` kwarg simply don't receive it.
-    The mesh is also handed to the loss (``loss_fn(..., mesh=...)``,
-    feature-detected the same way) so the fused LM-head cross-entropy can
-    shard_map its kernels over the head's vocab/batch axes.
+    The mesh is also handed to the loss (``loss_fn(..., mesh=...)``) so
+    the fused LM-head cross-entropy can shard_map its kernels over the
+    head's vocab/batch axes.
 
-    When the optimizer's ``update_params`` accepts ``grad_scale``, global-
-    norm clipping is folded into the parameter write (the clip factor
-    scales the gradient inside the kernels) instead of rescaling the grad
-    tree — one full grad read+write less per step, numerically identical
-    to clip-then-update.
+    Under the fused write, global-norm clipping is folded into the
+    parameter write (``update_params(..., grad_scale=...)``: the clip
+    factor scales the gradient inside the kernels) instead of rescaling
+    the grad tree — one full grad read+write less per step, numerically
+    identical to clip-then-update.
 
     ``donate=True`` returns the step already jitted with
     ``donate_argnums=(0,)``: the TrainState buffers are donated, which —
@@ -126,22 +123,12 @@ def make_train_step(cfg, tx: GradientTransformation, grad_accum: int = 1,
                          "update_params (fused parameter write)")
 
     up_kwargs = {}
-    if fused_apply:
-        accepted = inspect.signature(tx.update_params).parameters
-        if mesh is not None and "shardings" in accepted:
-            from repro.models import param_logical_axes, param_shapes
-            from repro.models.sharding import tree_shardings
-            up_kwargs["shardings"] = tree_shardings(
-                param_logical_axes(cfg), mesh, rules, param_shapes(cfg))
-        fuse_clip = clip_norm > 0 and "grad_scale" in accepted
-    else:
-        fuse_clip = False
-
-    # the fused-loss analog of the update_params feature-detection: only
-    # pass the mesh to losses that know what to do with it
-    loss_kwargs = {}
-    if mesh is not None and "mesh" in inspect.signature(loss_fn).parameters:
-        loss_kwargs["mesh"] = mesh
+    if fused_apply and mesh is not None:
+        from repro.models import param_logical_axes, param_shapes
+        from repro.models.sharding import tree_shardings
+        up_kwargs["shardings"] = tree_shardings(
+            param_logical_axes(cfg), mesh, rules, param_shapes(cfg))
+    fuse_clip = fused_apply and clip_norm > 0
 
     stats_fn = make_stats_fn(stats) if stats is not None else None
 
@@ -150,7 +137,7 @@ def make_train_step(cfg, tx: GradientTransformation, grad_accum: int = 1,
         # transpose naming, the bwd) under one label in trace viewers
         with jax.named_scope("fwd"):
             return loss_fn(params, cfg, mb, aux_coef=aux_coef, rules=rules,
-                           **loss_kwargs)
+                           mesh=mesh)
 
     grad_fn = jax.value_and_grad(loss_of, has_aux=True)
 
@@ -288,13 +275,9 @@ def make_train_step(cfg, tx: GradientTransformation, grad_accum: int = 1,
 def make_eval_step(cfg, rules: Optional[Rules] = None,
                    mesh: Optional[Mesh] = None):
     rules = rules or Rules(cfg.rule_overrides)
-    loss_kwargs = {}
-    if mesh is not None and "mesh" in inspect.signature(loss_fn).parameters:
-        loss_kwargs["mesh"] = mesh
 
     def eval_step(params, batch):
-        loss, metrics = loss_fn(params, cfg, batch, rules=rules,
-                                **loss_kwargs)
+        loss, metrics = loss_fn(params, cfg, batch, rules=rules, mesh=mesh)
         return {"loss": metrics["loss"], "perplexity": jnp.exp(metrics["loss"])}
 
     return eval_step

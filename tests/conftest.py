@@ -1,5 +1,5 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — tests must see the real single
-CPU device; only launch/dryrun.py forces 512 placeholder devices."""
+CPU device; tests that need more start a subprocess with forced devices."""
 import jax
 import jax.numpy as jnp
 import pytest

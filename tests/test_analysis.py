@@ -252,7 +252,7 @@ from jax.experimental.shard_map import shard_map
 
 
 def f(x, plan):
-    axes = plan.spec3[1]
+    axes = plan.of("rows")
     return lax.psum(x, axes) if axes else x
 
 

@@ -480,8 +480,8 @@ for dtype in (jnp.float32, jnp.bfloat16):
         do = jax.random.normal(ks[3], (B, S, H, hd), jnp.float32).astype(dtype)
         route, plan = dispatch.attn_route(q.shape, k.shape, True, None,
                                           qsh, qsh)
-        assert route == "kernel" and plan.batch_axes == ("data",) \
-            and plan.head_axes == ("model",), (route, plan)
+        assert route == "kernel" and plan.of("batch") == ("data",) \
+            and plan.of("heads") == ("model",), (route, plan)
         q_s, k_s, v_s = (jax.device_put(x, qsh) for x in (q, k, v))
 
         def f_fused(q, k, v):

@@ -180,6 +180,80 @@ def test_repro_fused_mode_keys_dispatch_cache(monkeypatch):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
 
+def _one_device_mesh(names=("data", "model")):
+    from jax.sharding import Mesh
+    return Mesh(np.array(jax.devices()[:1]).reshape(1, 1), names)
+
+
+# (family, shapes, specs, transposed, expected plan axes | None | "ref"),
+# one case per planner rule (module docstring of kernels/dispatch.py) plus
+# the plans the cells run. A spec of None is an operand with no sharding;
+# "single" a sharding that is not a NamedSharding; "other" a NamedSharding
+# on a second mesh; "wide" a (data=2) abstract mesh, for divisibility.
+PLAN_CASES = {
+    "r1-updates-no-sharding": ("updates", [(8, 16)], [None], False, None),
+    "r2-updates-not-named": ("updates", [(8, 16)], ["single"], False, "ref"),
+    "r2-xent-two-meshes": ("xent", [(4, 8, 16), (16, 32)],
+                           [P("data"), "other"], False, "ref"),
+    "r3-xent-seq-sharded": ("xent", [(4, 8, 16), (16, 32)],
+                            [P(None, "data"), None], False, "ref"),
+    "r3-attn-seq-sharded": ("attn", [(2, 8, 4, 16), (2, 8, 2, 16)],
+                            [P(None, "data"), None], False, "ref"),
+    "r4-attn-q-vs-kv": ("attn", [(2, 8, 4, 16), (2, 8, 2, 16)],
+                        [P("data"), P()], False, "ref"),
+    "r5-xent-tokens-and-vocab": ("xent", [(4, 8, 16), (16, 32)],
+                                 [P("data"), P(None, "data")], False, "ref"),
+    "r6-updates-indivisible": ("updates", [(3, 16)], [("wide", P("data"))],
+                               False, "ref"),
+    "r7-xent-only-embed-gathered": ("xent", [(4, 8, 16), (16, 32)],
+                                    [P(), P("data")], False, None),
+    "r7-attn-replicated": ("attn", [(2, 8, 4, 16), (2, 8, 2, 16)],
+                           [P(), P()], False, None),
+    "updates-rows-cols": ("updates", [(2, 8, 16)], [P(None, "data", "model")],
+                          False, (("rows", ("data",)), ("cols", ("model",)))),
+    "xent-tied-fsdp": ("xent", [(4, 8, 16), (32, 16)],
+                       [P("data"), P(None, "data")], True,
+                       (("tok", ("data",)),)),
+    "attn-batch-heads": ("attn", [(2, 8, 4, 16), (2, 8, 2, 16)],
+                         [P("data", None, "model")] * 2, False,
+                         (("batch", ("data",)), ("heads", ("model",)))),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PLAN_CASES))
+def test_plan_rules(case):
+    """One planner for the three kernel families, rule by rule."""
+    from jax.sharding import AbstractMesh, SingleDeviceSharding
+    family, shapes, specs, transposed, want = PLAN_CASES[case]
+    mesh = _one_device_mesh()
+
+    def sharding(spec):
+        if spec is None:
+            return None
+        if spec == "single":
+            return SingleDeviceSharding(jax.devices()[0])
+        if spec == "other":
+            return NamedSharding(_one_device_mesh(("x", "y")), P())
+        if isinstance(spec, tuple):
+            return NamedSharding(AbstractMesh((2, 1), ("data", "model")),
+                                 spec[1])
+        return NamedSharding(mesh, spec)
+
+    shs = [sharding(sp) for sp in specs]
+    if family == "updates":
+        ops = dispatch._update_operands(shs[0], shapes[0])
+    elif family == "xent":
+        ops = dispatch._xent_operands(*shs, *shapes, transposed)
+    else:
+        ops = dispatch._attn_operands(*shs, *shapes)
+    got = dispatch._plan(ops)
+    if isinstance(want, tuple):
+        assert got == dispatch.Plan(mesh, want), got
+        assert all(got.of(role) == axes for role, axes in want)
+    else:
+        assert got == want, got
+
+
 def test_make_host_mesh_rejects_non_divisor():
     from repro.launch.mesh import make_host_mesh
     n = len(jax.devices())

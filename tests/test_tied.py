@@ -364,8 +364,8 @@ for dtype in (jnp.float32, jnp.bfloat16):
     w_sh = NamedSharding(mesh, P("model", "data"))
     route, plan = dispatch.xent_route(h.shape, wt.shape, None, h_sh, w_sh,
                                       transposed=True)
-    assert route == "kernel" and plan.tok_axes == ("data",) \
-        and plan.voc_axes == ("model",), (route, plan)
+    assert route == "kernel" and plan.of("tok") == ("data",) \
+        and plan.of("voc") == ("model",), (route, plan)
     h_s, w_s = jax.device_put(h, h_sh), jax.device_put(wt, w_sh)
 
     def f_fused(h, wt):

@@ -377,8 +377,8 @@ for dtype in (jnp.float32, jnp.bfloat16):
     h_sh = NamedSharding(mesh, P("data", None, None))
     w_sh = NamedSharding(mesh, P("data", "model"))  # FSDP embed + TP vocab
     route, plan = dispatch.xent_route(h.shape, w.shape, None, h_sh, w_sh)
-    assert route == "kernel" and plan.tok_axes == ("data",) \
-        and plan.voc_axes == ("model",), (route, plan)
+    assert route == "kernel" and plan.of("tok") == ("data",) \
+        and plan.of("voc") == ("model",), (route, plan)
     h_s, w_s = jax.device_put(h, h_sh), jax.device_put(w, w_sh)
 
     def f_fused(h, w):
